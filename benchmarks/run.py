@@ -25,6 +25,9 @@ def main() -> None:
     ap.add_argument("--only", default="", help="run only benches whose name starts with this")
     args = ap.parse_args()
 
+    from repro.runtime.caches import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (artifact_bench, attn_bench, engine_bench,
                             kernel_bench, moe_bench, paper_tables, serve_bench)
 

@@ -109,8 +109,10 @@ def _select_top_r(frac: Array, r: Array) -> Array:
     fb = jax.lax.bitcast_convert_type(frac.astype(jnp.float32), jnp.int32)
     # frac >= 0, so bit patterns order like the floats; find the smallest
     # threshold t with count(fb > t) <= r  (invariant: lo fails, hi holds)
-    lo = jnp.full(frac.shape[:-1] + (1,), -1, jnp.int32)
-    hi = jnp.full(frac.shape[:-1] + (1,), jnp.int32(0x7F7FFFFF))
+    # built from fb so the carry inherits fb's varying manual axes when this
+    # runs inside shard_map (a jnp.full carry would be device-invariant)
+    lo = jnp.full_like(fb[..., :1], -1)
+    hi = jnp.full_like(fb[..., :1], 0x7F7FFFFF)
 
     def body(_, state):
         lo, hi = state

@@ -12,7 +12,8 @@ encoder too"): ``get_encode_params`` mirrors ``get_tiles`` dispatch.
 Cache
 -----
 * location: ``$REPRO_PVQ_TUNE_CACHE`` if set, else
-  ``~/.cache/repro/pvq_tune_cache.json``
+  ``<repo>/.pvq_tune_cache.json`` (``repro.runtime.caches``: inside the
+  checkout, next to the compile cache)
 * matmul key: ``"m x k x n : g<group> : <dtype> : <backend> : kv<N> : v3"``
   (no spaces) — ``kv<N>`` is ``pvq_matmul.KERNEL_VERSION``, so a material
   kernel body change (e.g. the v3 quantized-activation contraction)
@@ -56,7 +57,7 @@ from typing import Dict, Iterable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .pvq_encode import ENCODE_KERNEL_VERSION, default_sort_impl, pvq_encode_batch
+from .pvq_encode import ENCODE_KERNEL_VERSION, pvq_encode_batch
 from .pvq_matmul import (
     KERNEL_VERSION,
     normalize_tiles,
@@ -87,7 +88,9 @@ def cache_path() -> Path:
     env = os.environ.get("REPRO_PVQ_TUNE_CACHE", "").strip()
     if env:
         return Path(env)
-    return Path.home() / ".cache" / "repro" / "pvq_tune_cache.json"
+    from repro.runtime.caches import TUNE_CACHE_FILE
+
+    return TUNE_CACHE_FILE
 
 
 def cache_key(m: int, k: int, n: int, group: int, dtype, backend: str) -> str:
@@ -210,7 +213,8 @@ def _note(key: str, outcome: str, search_s: float = 0.0) -> None:
 def heuristic_tiles(m: int, k: int, n: int, group: int) -> Tuple[int, int, int]:
     """Static MXU-aligned guess: full 128 tiles clamped to the problem, with a
     deeper bk when the k extent dwarfs the MXU (fewer grid steps, same VMEM
-    order) and a skinny bm for decode-like m."""
+    order) and a skinny bm for decode-like m; ``normalize_tiles`` then
+    widens bk to a block Mosaic accepts."""
     bk = 128 if k <= 1024 else 256
     return normalize_tiles(m, k, n, group, bm=128, bn=128, bk=bk)
 
@@ -222,7 +226,8 @@ def candidate_tiles(
 
     bm sweeps sublane-aligned powers of two (8..256) — decode steps live at
     the small end; bn sweeps lane multiples (128..512); bk sweeps group
-    multiples (group..512).  Candidates whose VMEM working set exceeds the
+    multiples (group..512), which ``normalize_tiles`` widens to the blocks
+    Mosaic accepts.  Candidates whose VMEM working set exceeds the
     budget are dropped.  The heuristic default is always candidate #0 so a
     truncated search can never be worse than no search.
     """
@@ -376,21 +381,15 @@ def encode_candidates(
 def _time_encode_candidate(
     w, k_pulses: int, cand: Tuple[int, int], reps: int, interpret: bool
 ) -> float:
-    # time the same bulk-allocation lowering production dispatch will use
-    # (REPRO_PVQ_ENCODE_SORT=bisect tunes — and works — on Mosaic versions
-    # whose argsort path doesn't lower at all)
     bg, delta_max = cand
-    sort_impl = default_sort_impl()
     p, _ = pvq_encode_batch(
-        w, k_pulses=k_pulses, bg=bg, delta_max=delta_max, interpret=interpret,
-        sort_impl=sort_impl,
+        w, k_pulses=k_pulses, bg=bg, delta_max=delta_max, interpret=interpret
     )
     p.block_until_ready()  # warmup: trace + compile outside the timed region
     t0 = time.perf_counter()
     for _ in range(reps):
         pvq_encode_batch(
-            w, k_pulses=k_pulses, bg=bg, delta_max=delta_max,
-            interpret=interpret, sort_impl=sort_impl,
+            w, k_pulses=k_pulses, bg=bg, delta_max=delta_max, interpret=interpret
         )[0].block_until_ready()
     return (time.perf_counter() - t0) / reps
 

@@ -4,9 +4,12 @@ Every caller (nn layers, serve, QAT, grad-compress, checkpointer) goes through
 this module rather than the kernel files, so backend selection, tile
 autotuning, and the int8 pulse contract live in exactly one place:
 
-* backend: on a CPU host (this container) kernels run with ``interpret=True``
-  — the kernel body executes in Python on CPU, validating logic against
-  ref.py; on a TPU backend the same calls compile to Mosaic.
+* backend: where JAX's default backend is the TPU, every kernel compiles
+  to Mosaic; elsewhere the same calls run ``interpret=True`` (the kernel
+  body executes on the CPU, checked against ref.py).  Interpret mode checks
+  logic only — block shapes and primitives Mosaic refuses pass there — so
+  ``tests/test_tpu_compile.py`` compiles the main-path kernels for a
+  described v5e chip.
 * tiles: ``pvq_matmul`` consults the persistent autotune cache
   (``repro.kernels.autotune``); explicit tiles still win, and
   ``REPRO_PVQ_AUTOTUNE=1`` enables search-on-miss.
@@ -372,15 +375,6 @@ def pvq_attn_decode(
 # ---------------------------------------------------------------------------
 
 
-def _encode_sort_impl(sort_impl: str | None) -> str:
-    """``'argsort'`` (default) or ``'bisect'`` — the Mosaic fallback when a
-    toolchain rejects in-kernel ``jnp.argsort``; overridable per-process via
-    ``REPRO_PVQ_ENCODE_SORT=bisect``."""
-    from .pvq_encode import default_sort_impl
-
-    return sort_impl if sort_impl is not None else default_sort_impl()
-
-
 def pvq_encode(
     w,
     *,
@@ -388,9 +382,9 @@ def pvq_encode(
     bg: int | None = None,
     delta_max: int | None = None,
     interpret: bool | None = None,
-    sort_impl: str | None = None,
 ):
-    """Batched PVQ projection onto P(N, K) (sort-based, bounded correction).
+    """Batched PVQ projection onto P(N, K) (largest-remainder, bounded
+    greedy correction).
 
     Returns (pulses i32 (g, n), rho_ls f32 (g,)).  ``delta_max >= k_pulses``
     reproduces the exact greedy search.  ``bg``/``delta_max`` default to the
@@ -412,7 +406,6 @@ def pvq_encode(
         bg=bg,
         delta_max=delta_max,
         interpret=interpret,
-        sort_impl=_encode_sort_impl(sort_impl),
     )
 
 
@@ -472,9 +465,10 @@ def pvq_encode_grouped_fast(
     delta_max: int | None = None,
     scale_mode: str = "ls",
 ):
-    """Grouped encode of a flat vector on the fast sorted path.
+    """Grouped encode of a flat vector on the fast largest-remainder path.
 
-    Dispatches to the Pallas kernel on TPU and the jnp sorted encoder
+    Dispatches to the Pallas kernel on TPU and its jnp twin
+    (``core.pvq.pvq_quantize_direction_fast``, bit-identical output)
     elsewhere (interpret-mode Pallas is a correctness proxy, not a fast path
     on CPU).  Returns (pulses i32 (G, group), rho f32 (G,)); trailing
     zero-padding never receives pulses.  The kernel natively emits the ``ls``

@@ -1,4 +1,4 @@
-"""Batched PVQ encoding Pallas TPU kernel (sort-based O(N log N + ΔK) search).
+"""Batched PVQ encoding Pallas TPU kernel (largest-remainder + bounded greedy).
 
 The paper needed a CUDA implementation to PVQ-encode million-dimensional
 layers with the exact greedy O(NK) search; the follow-up work (PVQ for LLMs,
@@ -8,7 +8,8 @@ correction.  This kernel implements that fast path:
 
   1. floor-init:  y = floor(K * |w| / ||w||_1)              (O(N))
   2. largest-remainder: give all but the last ``delta_max`` missing pulses to
-     the coordinates with the biggest fractional parts (one sort, O(N log N))
+     the coordinates with the biggest fractional parts (a 32-step threshold
+     bisection, O(32 N), in place of a sort)
   3. bounded greedy correction: place the final ``min(remaining, delta_max)``
      pulses with the exact cosine-maximizing argmax step (O(N * delta_max))
 
@@ -27,73 +28,60 @@ the gradient compressor's hot path (via ``kernels.ops``).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 #: bump on material kernel-body changes — the encoder autotune cache keys
 #: carry ``ekv{ENCODE_KERNEL_VERSION}`` so stale (bg, delta_max) timings miss.
-ENCODE_KERNEL_VERSION = 1
+ENCODE_KERNEL_VERSION = 2  # v2: bisect bulk mask, MXU prefix count
 
 
-def _bulk_mask_argsort(frac, bulk):
-    """0/1 mask of the ``bulk`` largest fracs per row (ties -> lower lane),
-    via one stable sort — the default bulk-allocation path."""
-    order = jnp.argsort(-frac, axis=-1, stable=True)
-    rank = jnp.argsort(order, axis=-1, stable=True)  # rank 0 = biggest frac
-    return (rank < bulk[:, None]).astype(jnp.float32)
+def _bulk_mask(frac, bulk):
+    """0/1 mask of the ``bulk`` (bg, 1) largest fracs per row, ties -> lower
+    lane (the stable-descending-sort selection), computed without a sort:
+    threshold-count binary search over the IEEE bit patterns of ``frac``
+    (>= 0, so int32 bit patterns order like the floats).
 
-
-def _bulk_mask_bisect(frac, bulk):
-    """Same mask, no sort: threshold-count binary search over the IEEE bit
-    patterns of ``frac`` (>= 0, so int32 bit patterns order like the floats).
-
-    Elementwise compares + lane reductions + a cumsum only — the Mosaic
-    fallback for toolchain versions that reject ``jnp.argsort`` inside a
-    kernel body.  Tie-break (equal fracs -> lower lane first) matches the
-    stable argsort bit-for-bit.
+    Mosaic lowers neither ``sort`` nor ``cumsum`` nor integer lane
+    reductions, so counts are f32 sums (exact below 2**24 lanes) and the
+    tie ranks come from the MXU: ``eq @ U`` with ``U`` the upper-triangular
+    ones matrix is the inclusive prefix count of ``eq`` along the lanes.
     """
     fb = jax.lax.bitcast_convert_type(frac.astype(jnp.float32), jnp.int32)
-    r = bulk[:, None]
-    lo = jnp.full((frac.shape[0], 1), -1, jnp.int32)
-    hi = jnp.full((frac.shape[0], 1), jnp.int32(0x7F7FFFFF))
+    r = bulk.astype(jnp.float32)
+    lo = jnp.full_like(fb[:, :1], -1)
+    hi = jnp.full_like(fb[:, :1], 0x7F7FFFFF)
+
+    def count(mask):
+        return jnp.sum(mask.astype(jnp.float32), axis=-1, keepdims=True)
 
     def body(_, state):
         # invariant: count(fb > lo) > r fails, count(fb > hi) <= r holds
         lo, hi = state
         mid = lo + (hi - lo) // 2
-        cnt = jnp.sum((fb > mid).astype(jnp.int32), axis=-1, keepdims=True)
-        ok = cnt <= r
+        ok = count(fb > mid) <= r
         return jnp.where(ok, lo, mid), jnp.where(ok, mid, hi)
 
     _, hi = jax.lax.fori_loop(0, 32, body, (lo, hi))
     gt = fb > hi
-    extra = r - jnp.sum(gt.astype(jnp.int32), axis=-1, keepdims=True)
+    extra = r - count(gt)
     eq = fb == hi
-    eq_rank = jnp.cumsum(eq.astype(jnp.int32), axis=-1)
+    n = frac.shape[-1]
+    upper = (
+        jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+        <= jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    ).astype(jnp.float32)
+    eq_rank = jnp.dot(
+        eq.astype(jnp.float32), upper, preferred_element_type=jnp.float32
+    )
     return (gt | (eq & (eq_rank <= extra))).astype(jnp.float32)
 
 
-_BULK_MASKS = {"argsort": _bulk_mask_argsort, "bisect": _bulk_mask_bisect}
-
-
-def default_sort_impl() -> str:
-    """Process-wide bulk-allocation lowering: ``REPRO_PVQ_ENCODE_SORT``
-    (``bisect`` = the no-argsort Mosaic fallback) or ``argsort``.  Every
-    defaulted dispatch — ``ops.pvq_encode`` and the autotune timing runs —
-    resolves through this, so tuned timings measure the lowering that
-    production will actually run."""
-    return os.environ.get("REPRO_PVQ_ENCODE_SORT", "").strip() or "argsort"
-
-
-def _kernel(w_ref, p_ref, rho_ref, *, k_pulses: int, delta_max: int,
-            sort_impl: str = "argsort"):
+def _kernel(w_ref, p_ref, rho_ref, *, k_pulses: int, delta_max: int):
+    # every per-row quantity stays (bg, 1): Mosaic keeps 2-D layouts
     w = w_ref[...].astype(jnp.float32)  # (bg, n)
     bg, n = w.shape
     absw = jnp.abs(w)
@@ -102,35 +90,34 @@ def _kernel(w_ref, p_ref, rho_ref, *, k_pulses: int, delta_max: int,
     target = absw * (k_pulses / safe)  # real-valued pyramid allocation
     y = jnp.where(l1 > 0, jnp.floor(target), 0.0)
 
-    # ---- largest-remainder bulk allocation (one sort — or, for Mosaic
-    # versions without in-kernel argsort, a bit-space binary search)
-    remaining = (k_pulses - jnp.sum(y, axis=-1)).astype(jnp.int32)  # (bg,)
+    # ---- largest-remainder bulk allocation
+    remaining = (k_pulses - jnp.sum(y, axis=-1, keepdims=True)).astype(jnp.int32)
     bulk = jnp.maximum(remaining - delta_max, 0)
     frac = target - y
-    bump = _BULK_MASKS[sort_impl](frac, bulk)
+    bump = _bulk_mask(frac, bulk)
     y = y + jnp.where(l1 > 0, bump, 0.0)
 
     # ---- bounded greedy correction: exact argmax placement of the last few
-    corr = jnp.sum(absw * y, axis=-1)  # (bg,)
-    energy = jnp.sum(y * y, axis=-1)
+    corr = jnp.sum(absw * y, axis=-1, keepdims=True)
+    energy = jnp.sum(y * y, axis=-1, keepdims=True)
     remaining = jnp.minimum(remaining, delta_max)
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (bg, n), 1)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (bg, n), 1).astype(jnp.float32)
 
     def body(_, state):
         y, corr, energy, remaining = state
-        num = (corr[:, None] + absw) ** 2
-        den = energy[:, None] + 2.0 * y + 1.0
+        num = (corr + absw) ** 2
+        den = energy + 2.0 * y + 1.0
         score = num / den
         best = jnp.max(score, axis=-1, keepdims=True)
-        # first-lane-wins one-hot of the argmax
-        is_best = (score == best).astype(jnp.int32)
-        first = jnp.argmax(is_best, axis=-1)
-        onehot = (lanes == first[:, None]).astype(jnp.float32)
-        do = (remaining > 0).astype(jnp.float32)[:, None]
-        upd = onehot * do
+        # first-lane-wins one-hot of the argmax (lane ids exact in f32)
+        first = jnp.min(
+            jnp.where(score == best, lanes, float(n)), axis=-1, keepdims=True
+        )
+        do = (remaining > 0).astype(jnp.float32)
+        upd = (lanes == first).astype(jnp.float32) * do
         y = y + upd
-        corr = corr + jnp.sum(absw * upd, axis=-1)
-        energy = energy + jnp.sum((2.0 * y - 1.0) * upd, axis=-1)
+        corr = corr + jnp.sum(absw * upd, axis=-1, keepdims=True)
+        energy = energy + jnp.sum((2.0 * y - 1.0) * upd, axis=-1, keepdims=True)
         remaining = remaining - (remaining > 0).astype(jnp.int32)
         return (y, corr, energy, remaining)
 
@@ -138,14 +125,16 @@ def _kernel(w_ref, p_ref, rho_ref, *, k_pulses: int, delta_max: int,
     y, _, _, _ = jax.lax.fori_loop(0, n_iter, body, (y, corr, energy, remaining))
     pulses = jnp.sign(w) * y
     p_ref[...] = pulses.astype(jnp.int32)
-    ynorm2 = jnp.sum(pulses * pulses, axis=-1)
-    rho = jnp.sum(w * pulses, axis=-1) / jnp.where(ynorm2 > 0, ynorm2, 1.0)
-    rho_ref[...] = jnp.where(ynorm2 > 0, jnp.maximum(rho, 0.0), 0.0)[:, None]
+    ynorm2 = jnp.sum(pulses * pulses, axis=-1, keepdims=True)
+    rho = jnp.sum(w * pulses, axis=-1, keepdims=True) / jnp.where(
+        ynorm2 > 0, ynorm2, 1.0
+    )
+    rho_ref[...] = jnp.where(ynorm2 > 0, jnp.maximum(rho, 0.0), 0.0)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("k_pulses", "bg", "delta_max", "interpret", "sort_impl"),
+    static_argnames=("k_pulses", "bg", "delta_max", "interpret"),
 )
 def pvq_encode_batch(
     w: jax.Array,  # (g, n) f32/bf16 groups to encode
@@ -154,20 +143,14 @@ def pvq_encode_batch(
     bg: int = 8,
     delta_max: int = 32,
     interpret: bool = False,
-    sort_impl: str = "argsort",
 ):
     """Returns (pulses i32 (g, n), rho_ls f32 (g,)).
 
-    ``delta_max`` bounds the exact greedy correction after the sort-based
-    allocation; ``delta_max >= k_pulses`` degenerates to the exact greedy
+    ``delta_max`` bounds the exact greedy correction after the
+    largest-remainder allocation; ``delta_max >= k_pulses`` degenerates to the exact greedy
     search.  Group counts that don't tile by ``bg`` are zero-padded (zero rows
-    encode to zero pulses / zero rho) and sliced back.  ``sort_impl``
-    selects the bulk-allocation lowering: ``'argsort'`` (default) or
-    ``'bisect'`` (elementwise + reductions only; bit-identical output) for
-    Mosaic versions that reject in-kernel ``jnp.argsort``.
+    encode to zero pulses / zero rho) and sliced back.
     """
-    if sort_impl not in _BULK_MASKS:
-        raise ValueError(f"sort_impl must be one of {tuple(_BULK_MASKS)}, got {sort_impl!r}")
     g, n = w.shape
     bg = min(bg, g)
     pad = (-g) % bg
@@ -175,9 +158,7 @@ def pvq_encode_batch(
         w = jnp.concatenate([w, jnp.zeros((pad, n), w.dtype)], axis=0)
     gp = g + pad
     pulses, rho = pl.pallas_call(
-        functools.partial(
-            _kernel, k_pulses=k_pulses, delta_max=delta_max, sort_impl=sort_impl
-        ),
+        functools.partial(_kernel, k_pulses=k_pulses, delta_max=delta_max),
         grid=(gp // bg,),
         in_specs=[pl.BlockSpec((bg, n), lambda i: (i, 0))],
         out_specs=[
@@ -189,6 +170,6 @@ def pvq_encode_batch(
             jax.ShapeDtypeStruct((gp, 1), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
     )(w)
     return pulses[:g], rho[:g, 0]

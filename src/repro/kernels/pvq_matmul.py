@@ -48,15 +48,13 @@ sizes are normally chosen by ``repro.kernels.autotune`` via ``kernels.ops``.
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 #: bumped whenever the kernel body changes materially; feeds the autotune
 #: cache key so stale tile timings from an older body never win dispatch.
@@ -149,19 +147,30 @@ def normalize_tiles(
     bk is rounded to a multiple of ``group`` (the dequant reshape needs it);
     all tiles are clamped to the padded problem extent.  Any remainder is
     handled by zero-padding in :func:`pvq_matmul`, not by the caller.
+
+    Every backend gets the tiles Mosaic accepts: the last two dims of a TPU
+    block must be (8, 128)-aligned or span the whole array.  The scales
+    block is ``(bk // group, bn)``, so a bk short of the whole contraction
+    holds a multiple of 8 groups (and a multiple of 128 lanes for the x
+    block) and divides ``k`` (no padded contraction); otherwise bk grows to
+    the whole ``k``.
     """
     def _round_up(v: int, mult: int) -> int:
         return -(-v // mult) * mult
 
     # sublane (8) / lane (128) alignment: never tile wider than the padded
     # problem, never narrower than one aligned vector register row
-    bm = max(min(bm, _round_up(m, 8)), 1)
-    bn = max(min(bn, _round_up(n, 128)), min(n, 128))
+    bm = min(_round_up(max(bm, 1), 8), _round_up(m, 8))
+    bn = n if n < 128 else min(_round_up(max(bn, 1), 128), _round_up(n, 128))
     bk = max(min(bk, k), 1)
     # bk must be a group multiple for the (bk//group, group, bn) dequant view
     if bk % group:
         bk = max((bk // group) * group, min(group, k))
-    return bm, bn, bk
+    step = math.lcm(8 * group, 128)
+    bk = _round_up(bk, step) if bk < k else k
+    while bk < k and k % bk:
+        bk += step
+    return bm, bn, min(bk, k)
 
 
 def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
@@ -234,7 +243,7 @@ def pvq_matmul(
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(*operands)
@@ -293,6 +302,13 @@ def pvq_matmul_batched(
 # ---------------------------------------------------------------------------
 
 
+#: int8 x int8 MXU dots accumulate exactly in int32 at the default
+#: precision.  Pinned so that an ambient ``jax_default_matmul_precision``
+#: ("highest", e.g. around an f32 reference) never reaches Mosaic, which
+#: refuses fp32 contract precision on int8 operands.
+_INT8_DOT = jax.lax.Precision.DEFAULT
+
+
 def _contract_int8_q(x, w, s, group: int, a_tile=None) -> jax.Array:
     """Fully integer tile contraction: per group-slice, one int8 x int8 dot
     with ``preferred_element_type=int32`` (the MXU accumulates in int32),
@@ -319,6 +335,7 @@ def _contract_int8_q(x, w, s, group: int, a_tile=None) -> jax.Array:
         part = jax.lax.dot_general(
             xg, wg, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.int32,
+            precision=_INT8_DOT,
         )  # (G, bm, bn) int32
         part = part.astype(jnp.float32) * s[:, None, :]
         if a_tile is not None:
@@ -331,6 +348,7 @@ def _contract_int8_q(x, w, s, group: int, a_tile=None) -> jax.Array:
         part = jax.lax.dot_general(
             xg, wg, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32,
+            precision=_INT8_DOT,
         )
         part = part.astype(jnp.float32) * s[g, :][None, :]
         if a_tile is not None:
@@ -438,8 +456,6 @@ def _kernel_q_dma(
         )
 
     _dma(0, 0).start()
-    x = x_ref[...]  # (bm, k_pad) int8
-    s = s_ref[...]  # (k_pad // group, bn) f32
 
     def body(idx, acc):
         slot = idx % 2
@@ -449,8 +465,10 @@ def _kernel_q_dma(
             _dma((idx + 1) % 2, idx + 1).start()
 
         _dma(slot, idx).wait()
-        xc = jax.lax.dynamic_slice(x, (0, idx * bk), (x.shape[0], bk))
-        sc = jax.lax.dynamic_slice(s, (idx * gpc, 0), (gpc, bn))
+        # x (bm, k_pad) int8 / s (k_pad // group, bn) f32: slice the refs,
+        # not loaded values (Mosaic has no dynamic_slice on values)
+        xc = x_ref[:, pl.ds(pl.multiple_of(idx * bk, bk), bk)]
+        sc = s_ref[pl.ds(pl.multiple_of(idx * gpc, gpc), gpc), :]
         return acc + _contract_int8_q(xc, wbuf[slot], sc, group)
 
     acc = jax.lax.fori_loop(
@@ -540,7 +558,7 @@ def pvq_matmul_q(
         )
         in_specs = [
             pl.BlockSpec((bm, kp), lambda i, j: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # pulses stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # pulses stay in HBM
             pl.BlockSpec((kp // group, bn), lambda i, j: (0, j)),
             pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
         ]
@@ -563,7 +581,7 @@ def pvq_matmul_q(
                 pltpu.SemaphoreType.DMA((2,)),
             ],
             interpret=interpret,
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")
             ),
         )(*operands)
@@ -601,7 +619,7 @@ def pvq_matmul_q(
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.dtype(out_dtype)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(*operands)
@@ -667,13 +685,13 @@ _ATTN_NEG_INF = -1e30
 
 
 def _attn_kernel_q(
+    len_ref,  # (BH,) int32 SMEM scalar prefetch: valid kv length per row
     q_ref,  # (1, m, hd) int8 quantized queries (m = heads per kv head)
     a_ref,  # (1, m, 1) f32 per-row query scales
     kp_ref,  # (1, bs, hd) int8 packed K pulses
     ks_ref,  # (1, bs, ng) f32 per-group K rho
     vp_ref,  # (1, bs, hd) int8 packed V pulses
     vs_ref,  # (1, bs, ng) f32 per-group V rho
-    len_ref,  # (1, 1) int32 valid kv length for this (batch, kv-head)
     o_ref,  # (1, m, hd) f32 out: UNNORMALIZED output accumulator
     mo_ref,  # (1, m, 1) f32 out: running row max
     lo_ref,  # (1, m, 1) f32 out: running softmax denominator
@@ -701,7 +719,7 @@ def _attn_kernel_q(
 
     bs, hd = kp_ref.shape[1], kp_ref.shape[2]
     ng = hd // group
-    kv_len = len_ref[0, 0]
+    kv_len = len_ref[pl.program_id(0)]
     m_rows = q_ref.shape[1]
     cols = si * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
     valid = cols < kv_len  # (1, bs)
@@ -714,6 +732,7 @@ def _attn_kernel_q(
         part = jax.lax.dot_general(
             qg, kg, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.int32,
+            precision=_INT8_DOT,
         )  # (m, bs) int32
         scores = scores + part.astype(jnp.float32) * ks_ref[0, :, g][None, :]
     scores = scores * a_ref[0] * sm_scale
@@ -739,6 +758,7 @@ def _attn_kernel_q(
         out_g = jax.lax.dot_general(
             pq, vg, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32,
+            precision=_INT8_DOT,
         )  # (m, group) int32
         outs.append(out_g.astype(jnp.float32) * s_p)
     acc_ref[...] = acc_ref[...] * alpha + (
@@ -812,38 +832,38 @@ def pvq_attn_q(
     )
     acc, m_run, l_run = pl.pallas_call(
         kernel,
-        grid=(bh, n_s),
-        in_specs=[
-            pl.BlockSpec((1, mp, hd), lambda b, si: (b, 0, 0)),
-            pl.BlockSpec((1, mp, 1), lambda b, si: (b, 0, 0)),
-            pl.BlockSpec((1, bs, hd), lambda b, si: (b, si, 0)),
-            pl.BlockSpec((1, bs, ng), lambda b, si: (b, si, 0)),
-            pl.BlockSpec((1, bs, hd), lambda b, si: (b, si, 0)),
-            pl.BlockSpec((1, bs, ng), lambda b, si: (b, si, 0)),
-            pl.BlockSpec(
-                (1, 1), lambda b, si: (b, 0), memory_space=pltpu.SMEM
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,  # kv_len rides in SMEM, read per row
+            grid=(bh, n_s),
+            in_specs=[
+                pl.BlockSpec((1, mp, hd), lambda b, si, L: (b, 0, 0)),
+                pl.BlockSpec((1, mp, 1), lambda b, si, L: (b, 0, 0)),
+                pl.BlockSpec((1, bs, hd), lambda b, si, L: (b, si, 0)),
+                pl.BlockSpec((1, bs, ng), lambda b, si, L: (b, si, 0)),
+                pl.BlockSpec((1, bs, hd), lambda b, si, L: (b, si, 0)),
+                pl.BlockSpec((1, bs, ng), lambda b, si, L: (b, si, 0)),
+            ],
+            out_specs=(
+                pl.BlockSpec((1, mp, hd), lambda b, si, L: (b, 0, 0)),
+                pl.BlockSpec((1, mp, 1), lambda b, si, L: (b, 0, 0)),
+                pl.BlockSpec((1, mp, 1), lambda b, si, L: (b, 0, 0)),
             ),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, mp, hd), lambda b, si: (b, 0, 0)),
-            pl.BlockSpec((1, mp, 1), lambda b, si: (b, 0, 0)),
-            pl.BlockSpec((1, mp, 1), lambda b, si: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((mp, hd), jnp.float32),
+                pltpu.VMEM((mp, 1), jnp.float32),
+                pltpu.VMEM((mp, 1), jnp.float32),
+            ],
         ),
         out_shape=(
             jax.ShapeDtypeStruct((bh, mp, hd), jnp.float32),
             jax.ShapeDtypeStruct((bh, mp, 1), jnp.float32),
             jax.ShapeDtypeStruct((bh, mp, 1), jnp.float32),
         ),
-        scratch_shapes=[
-            pltpu.VMEM((mp, hd), jnp.float32),
-            pltpu.VMEM((mp, 1), jnp.float32),
-            pltpu.VMEM((mp, 1), jnp.float32),
-        ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
-    )(qp, ap, kpp, ksp, vpp, vsp, kv_len.astype(jnp.int32)[:, None])
+    )(kv_len.astype(jnp.int32), qp, ap, kpp, ksp, vpp, vsp)
     if mp != m:
         acc, m_run, l_run = acc[:, :m], m_run[:, :m], l_run[:, :m]
     return acc, m_run, l_run

@@ -309,6 +309,41 @@ def poisson_trace(
     return out
 
 
+def prefill_in_chunks(model, params, prompt: Sequence[int], chunk_tokens: int):
+    """Last-position logits ``(1, 1, vocab)`` of ``prompt`` prefilled alone
+    through ``Model.prefill_chunk``, ``chunk_tokens`` (a page multiple) at a
+    time from position 0, on a private one-slot page pool whose page ``b``
+    holds block ``b``.  Needs an active ``KVQuant`` (page = its block).
+
+    This is what chunked prefill computes, for checks against
+    ``Model.prefill`` over the whole prompt: one chunk agrees to float
+    rounding, and later chunks differ only by reading the earlier ones
+    through PVQ-packed pages."""
+    page = int(default_kv_quant().block)
+    plen = len(prompt)
+    n_full = plen // page
+    n_pages = -(-plen // page)  # the trash page is n_pages
+    blocks = np.arange(n_pages)
+    table = jnp.asarray(np.where(blocks < n_full, blocks, n_pages)[None], jnp.int32)
+    no_write = jnp.full((1,), n_pages, jnp.int32)
+    cache = jax.tree.map(
+        lambda c: c.with_tables(table, no_write) if is_paged_kv(c) else c,
+        model.init_paged_cache(1, n_pages, n_pages),
+        is_leaf=is_paged_kv,
+    )
+    step = jax.jit(model.prefill_chunk)
+    for start in range(0, plen, chunk_tokens):
+        end = min(start + chunk_tokens, plen)
+        toks = np.zeros((1, chunk_tokens), np.int32)
+        toks[0, : end - start] = np.asarray(prompt[start:end], np.int32)
+        ids = start // page + np.arange(chunk_tokens // page)
+        ids = np.where(ids < n_full, ids, n_pages).astype(np.int32)
+        logits, cache = step(
+            params, cache, toks, np.int32(0), np.int32(start), ids, np.int32(plen)
+        )
+    return logits
+
+
 @dataclasses.dataclass
 class _Slot:
     req: Request
@@ -996,6 +1031,17 @@ class PVQEngine:
         _, self.cache = self._decode(
             self.params, self.cache, tokens, pos, self._page_table.copy(), wp
         )
+
+    def decode_hlo(self) -> str:
+        """StableHLO text of the decode step at the engine's static shapes,
+        lowered and not compiled.  Each Pallas kernel that lowers to Mosaic
+        appears as a ``tpu_custom_call``; an interpreted kernel does not."""
+        tokens = np.zeros((self.n_slots, 1), np.int32)
+        pos = np.zeros((self.n_slots,), np.int32)
+        wp = np.full((self.n_slots,), self.alloc.trash, np.int32)
+        return self._decode.lower(
+            self.params, self.cache, tokens, pos, self._page_table.copy(), wp
+        ).as_text()
 
     # ------------------------------------------------------------- run loop
 

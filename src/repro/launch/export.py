@@ -27,6 +27,7 @@ import time
 import jax
 
 from repro.checkpoint.artifact import write_pvqz
+from repro.runtime.caches import enable_compile_cache
 
 
 def export_arch(args) -> tuple:
@@ -97,6 +98,7 @@ def main() -> int:
                     help="fail (exit 1) if the MoE expert leaves alone "
                     "(*_experts pulse streams + scales) exceed this")
     args = ap.parse_args()
+    enable_compile_cache()
     if not args.arch and not args.paper_net:
         args.arch = "smollm-360m"
 

@@ -43,6 +43,7 @@ import argparse
 import json
 import time
 import weakref
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +55,7 @@ from repro.core.quantize import QuantPolicy, quantize_tree, total_bits
 from repro.launch.engine import bucket_len
 from repro.nn.models import build_model
 from repro.runtime import obs
+from repro.runtime.caches import enable_compile_cache
 
 # Actual XLA trace counts of the shared decode step (incremented by a
 # Python side effect that only runs while tracing).  The regression tests
@@ -256,7 +258,17 @@ def engine_token_agreement(model, params, requests, outputs) -> dict:
     }
 
 
-def main() -> int:
+def main(argv: Optional[Sequence[str]] = None) -> int:
+    """CLI entry point: serve, print the one-line JSON report, return the
+    exit code (1 when a gate fails)."""
+    rc, report = run(argv)
+    print(json.dumps(report))
+    return rc
+
+
+def run(argv: Optional[Sequence[str]] = None) -> Tuple[int, dict]:
+    """Parse ``argv`` (``sys.argv[1:]`` when None), serve, and return
+    ``(exit code, report)`` — the in-process form of :func:`main`."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
     ap.add_argument("--reduced", action="store_true")
@@ -388,7 +400,7 @@ def main() -> int:
         "write metrics.jsonl + a perfetto-loadable trace.json into DIR on "
         "exit (every exit path, gate failures included)",
     )
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.act_int8 and not (args.pvq or args.artifact):
         ap.error("--act-int8 quantizes the packed matmul activations; "
                  "it requires --pvq or --artifact")
@@ -399,6 +411,7 @@ def main() -> int:
         ap.error("--engine pages the PVQ-compressed KV cache (page = kv "
                  "block); it requires --kv-pvq")
 
+    enable_compile_cache()
     if args.metrics_out:
         obs.set_enabled(True)
     try:
@@ -434,7 +447,7 @@ def _probe_act_rows(params) -> None:
     quantize_activations(rows, aq)
 
 
-def _serve(args) -> int:
+def _serve(args) -> Tuple[int, dict]:
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -648,8 +661,7 @@ def _serve(args) -> int:
                 f"packed KV bytes ratio {packed_bpt / dense_bpt:.3f} > "
                 f"allowed {args.max_kv_bytes_ratio}"
             )
-            print(json.dumps(report))
-            return 1
+            return 1, report
 
     if obs.enabled() and args.act_int8:
         _probe_act_rows(params)
@@ -673,7 +685,13 @@ def _serve(args) -> int:
             prefill_batch=args.prefill_batch,
             prefix_cache=not args.no_prefix_cache,
         )
+        t0 = time.time()
         eng.warmup(prompt_lens=[len(r.prompt) for r in trace])
+        report["engine_warmup_s"] = round(time.time() - t0, 2)
+        # Pallas kernels that lowered to Mosaic (0 when they interpret)
+        report["engine_decode_mosaic_kernels"] = eng.decode_hlo().count(
+            "tpu_custom_call"
+        )
         res = eng.run(trace)
         outputs = res.pop("outputs")
         report["arch"] = cfg.name
@@ -711,14 +729,12 @@ def _serve(args) -> int:
                     f"engine token agreement {ag['engine_token_agreement']:.4f}"
                     f" < required {args.agreement_min}"
                 )
-                print(json.dumps(report))
-                return 1
+                return 1, report
         if args.min_speedup is not None and speedup < args.min_speedup:
             report["speedup_fail"] = (
                 f"engine speedup {speedup:.3f}x < required {args.min_speedup}x"
             )
-            print(json.dumps(report))
-            return 1
+            return 1, report
         if (
             args.min_prefix_hits is not None
             and res["prefix_hits"] < args.min_prefix_hits
@@ -727,10 +743,8 @@ def _serve(args) -> int:
                 f"prefix cache hits {res['prefix_hits']} < required "
                 f"{args.min_prefix_hits}"
             )
-            print(json.dumps(report))
-            return 1
-        print(json.dumps(report))
-        return 0
+            return 1, report
+        return 0, report
 
     key = jax.random.PRNGKey(args.seed + 1)
     tokens = jax.random.randint(key, (args.batch, args.prompt_len), 0, cfg.vocab_size)
@@ -779,11 +793,9 @@ def _serve(args) -> int:
                 f"top-1 agreement {ag['top1_agreement']:.4f} < required "
                 f"{args.agreement_min}"
             )
-            print(json.dumps(report))
-            return 1
+            return 1, report
 
-    print(json.dumps(report))
-    return 0
+    return 0, report
 
 
 if __name__ == "__main__":

@@ -3,9 +3,9 @@
     PYTHONPATH=src python -m repro.launch.train --arch smollm-360m --reduced \
         --steps 200 --batch 8 --seq 64 --ckpt-dir /tmp/run0 [--pvq-qat]
 
-Wires together: config -> model -> AdamW -> sharded step (mesh-aware when
-more than one device is present) -> deterministic data pipeline -> async
-checkpointing -> fault-tolerant runner.  ``--pvq-qat`` trains with the
+Wires together: config -> model -> AdamW -> jitted step on one device ->
+deterministic data pipeline -> async checkpointing -> fault-tolerant
+runner.  ``--pvq-qat`` trains with the
 paper's mixed-optimization recipe (STE PVQ projection on matmul weights).
 """
 
@@ -26,6 +26,7 @@ from repro.configs.base import ShapeConfig
 from repro.data import TokenLoader, TokenTask
 from repro.nn.models import build_model
 from repro.optim import AdamW, cosine_schedule
+from repro.runtime.caches import enable_compile_cache
 from repro.runtime.fault_tolerance import StragglerPolicy, TrainingRunner
 
 
@@ -80,6 +81,7 @@ def main() -> int:
     ap.add_argument("--pvq-k", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -26,17 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map with the 0.4.x fallback (jax.experimental.shard_map)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
-
-
 def pipeline_forward(
     stage_fn: Callable[[Any, jax.Array], jax.Array],
     stage_params: Any,  # leaves with leading [n_stages] axis, sharded P('pod')
@@ -93,11 +82,12 @@ def pipeline_forward(
         )
         return outputs[None]
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         per_pod,
         mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stage_params), P(None)),
         out_specs=P(axis),
+        check_vma=False,
     )
     out = fn(stage_params, x_microbatches)  # (n_stages, n_micro, mb, ...)
     return out[0]

@@ -234,7 +234,8 @@ def test_dma_streaming_matches_automatic_pipeline_bit_exact():
     the same per-chunk contraction in the same order as the automatic
     k-grid pipeline — outputs are bit-identical, with and without the
     bias/activation epilogue."""
-    m, k, n, group = 8, 512, 256, 128
+    # group 32: a legal bk of 8 groups leaves 4 k-chunks to stream
+    m, k, n, group = 8, 1024, 256, 32
     x, w, s = _problem(9, m, k, n, group)
     bias = jax.random.normal(jax.random.PRNGKey(10), (n,))
     xq, a = quantize_activations(x)
@@ -245,11 +246,11 @@ def test_dma_streaming_matches_automatic_pipeline_bit_exact():
     ):
         b = kwargs.pop("bias", None)
         y_dma = pvq_matmul_q(
-            xq, w, s, a, b, group=group, bk=128, dma_streaming=True,
+            xq, w, s, a, b, group=group, bk=256, dma_streaming=True,
             interpret=True, **kwargs,
         )
         y_pipe = pvq_matmul_q(
-            xq, w, s, a, b, group=group, bk=128, dma_streaming=False,
+            xq, w, s, a, b, group=group, bk=256, dma_streaming=False,
             interpret=True, **kwargs,
         )
         assert bool(jnp.array_equal(y_dma, y_pipe))

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import autotune, ops
+from repro.kernels.pvq_matmul import normalize_tiles
 from repro.kernels.ref import pvq_matmul_ref
 
 
@@ -30,6 +31,46 @@ def test_candidates_aligned_and_bounded():
         assert bk % 128 == 0  # group multiple
         assert bm <= 8 and bn <= 512 and bk <= 512  # clamped to the problem
     assert len(set(cands)) == len(cands)  # deduped
+
+
+def _mosaic_block_ok(block, array) -> bool:
+    """The TPU block-shape rule: each of the last two block dims is (8, 128)
+    aligned or spans the whole (padded) array dim."""
+    (br, bc), (ar, ac) = block, array
+    return (br % 8 == 0 or br == ar) and (bc % 128 == 0 or bc == ac)
+
+
+@pytest.mark.parametrize(
+    "m,k,n,group",
+    [
+        (8, 1024, 960, 256),  # smollm-360m decode, d_model GEMMs
+        (8, 2560, 960, 256),  # smollm-360m decode, FFN down
+        (128, 1024, 2560, 256),  # smollm-360m chunked prefill
+        (8, 960, 960, 64),
+        (3, 256, 64, 32),
+        (16, 512, 300, 16),
+        (5, 64, 96, 8),
+    ],
+)
+def test_every_tile_is_a_legal_tpu_block(m, k, n, group):
+    """normalize_tiles, heuristic_tiles and candidate_tiles only hand out
+    tiles whose x / pulse / scales blocks Mosaic accepts, so the CPU tests
+    run the tiles the chip compiles."""
+    raw = (1, 8, 100, 128, 256, 512, 4096)
+    tiles = {autotune.heuristic_tiles(m, k, n, group)}
+    tiles |= set(autotune.candidate_tiles(m, k, n, group, max_candidates=100))
+    tiles |= {
+        normalize_tiles(m, k, n, group, bm, bn, bk)
+        for bm in raw for bn in raw for bk in raw
+    }
+    for bm, bn, bk in tiles:
+        assert bk % group == 0 and bk <= k, (bm, bn, bk)
+        mp, kp, np_ = (-(-v // b) * b for v, b in ((m, bm), (k, bk), (n, bn)))
+        assert _mosaic_block_ok((bm, bk), (mp, kp)), ("x", bm, bn, bk)
+        assert _mosaic_block_ok((bk, bn), (kp, np_)), ("pulses", bm, bn, bk)
+        assert _mosaic_block_ok((bk // group, bn), (kp // group, np_)), (
+            "scales", bm, bn, bk,
+        )
 
 
 def test_autotune_persists_cache_file(tune_cache):

@@ -572,6 +572,37 @@ def test_engine_chunked_prefill_agreement_and_compiles(served):
         assert ag["engine_token_agreement"] >= 0.99
 
 
+#: chunked vs whole-prompt prefill: RMS of the logit difference over the
+#: std of the whole-prompt logits.  A chunk that reads earlier chunks
+#: through PVQ-packed pages measures 0.02-0.1 here; a dropped packed leg,
+#: chunk positions counted from 0, or one page of context missing measure
+#: 0.2-1.3.  A single chunk has no packed context: float rounding only.
+CHUNK_TOL = 0.15
+
+
+@pytest.mark.parametrize("chunk_pages,tol", [(4, 1e-4), (2, CHUNK_TOL), (1, CHUNK_TOL)])
+def test_prefill_chunk_matches_whole_prompt_prefill(served, chunk_pages, tol):
+    """``Model.prefill_chunk``, chunk by chunk, against ``Model.prefill``
+    over the whole prompt (a 29-token prompt: 3 full pages and a tail, in
+    1, 2 or 4 chunks)."""
+    from repro.launch.engine import prefill_in_chunks
+
+    cfg, model, params = served
+    whole_fn = jax.jit(model.prefill)
+    with kv_quant_scope(KVQ):
+        for seed in range(3):
+            prompt = jax.random.randint(
+                jax.random.PRNGKey(seed), (1, 29), 0, cfg.vocab_size
+            )
+            whole, _ = whole_fn(params, {"tokens": prompt})
+            chunked = prefill_in_chunks(
+                model, params, np.asarray(prompt[0]), chunk_pages * KVQ.block
+            )
+            w, c = whole[0, -1], chunked[0, -1]
+            rms = float(jnp.sqrt(jnp.mean((c - w) ** 2)) / jnp.std(w))
+            assert rms <= tol, (seed, rms)
+
+
 def test_engine_prefix_cache_share_cow_and_leakage(served):
     """Two requests sharing a 16-token prefix serialized through one slot:
     the second admission maps the first's parked prefix pages (counted

@@ -69,12 +69,8 @@ def test_wire_bytes_ratio():
 
 def test_cross_pod_mean_matches_pmean_at_high_k():
     """shard_map over a 1-axis mesh: compressed mean ~= exact mean."""
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
 
     devs = np.array(jax.devices()[:1]).reshape(1)
     mesh = Mesh(devs, ("pod",))

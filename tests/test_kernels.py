@@ -173,44 +173,36 @@ def test_pvq_encode_l1_constraint_large_k():
 
 
 @pytest.mark.parametrize("k_pulses,delta_max", [(48, 8), (192, 16), (64, 64)])
-def test_pvq_encode_bisect_fallback_bit_exact(k_pulses, delta_max):
-    """Satellite (ROADMAP "Mosaic sort fallback"): forcing the no-argsort
-    bulk allocation (threshold-count binary search; elementwise + reductions
-    only) reproduces the argsort path bit-for-bit — including fractional-part
-    ties, which quantized weights force below."""
+def test_pvq_encode_kernel_bit_exact_vs_fast_twin(k_pulses, delta_max):
+    """The Pallas encoder (threshold bisection + MXU prefix count in place of
+    a sort) reproduces ``core.pvq.pvq_quantize_direction_fast`` bit-for-bit,
+    including fractional-part ties, which quantized weights force below."""
+    from repro.core.pvq import pvq_quantize_direction_fast
     from repro.kernels.pvq_encode import pvq_encode_batch
 
     for seed in range(3):
         w = jnp.round(jax.random.laplace(jax.random.PRNGKey(seed), (16, 128)) * 4) / 4
-        pa, ra = pvq_encode_batch(
-            w, k_pulses=k_pulses, delta_max=delta_max, interpret=True,
-            sort_impl="argsort",
+        got, _ = pvq_encode_batch(
+            w, k_pulses=k_pulses, delta_max=delta_max, interpret=True
         )
-        pb, rb = pvq_encode_batch(
-            w, k_pulses=k_pulses, delta_max=delta_max, interpret=True,
-            sort_impl="bisect",
-        )
-        np.testing.assert_array_equal(np.asarray(pa), np.asarray(pb))
-        np.testing.assert_array_equal(np.asarray(ra), np.asarray(rb))
+        want = pvq_quantize_direction_fast(w, k_pulses, delta_max=delta_max)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_pvq_encode_sort_impl_env_dispatch(monkeypatch):
-    """REPRO_PVQ_ENCODE_SORT=bisect flips the ops-layer default."""
-    w = jax.random.laplace(jax.random.PRNGKey(7), (8, 128))
-    want_p, want_rho = ops.pvq_encode(w, k_pulses=32, interpret=True)
-    monkeypatch.setenv("REPRO_PVQ_ENCODE_SORT", "bisect")
-    got_p, got_rho = ops.pvq_encode(w, k_pulses=32, interpret=True)
-    np.testing.assert_array_equal(np.asarray(got_p), np.asarray(want_p))
-    np.testing.assert_array_equal(np.asarray(got_rho), np.asarray(want_rho))
+@pytest.mark.parametrize("n", [64, 256])
+def test_bulk_mask_prefix_count_matches_stable_sort(n):
+    """``_bulk_mask`` selects exactly the ``bulk`` largest fracs per row with
+    ties going to the lower lane: the stable-descending-sort selection,
+    checked against numpy on rows built to be full of ties."""
+    from repro.kernels.pvq_encode import _bulk_mask
 
-
-def test_pvq_encode_rejects_unknown_sort_impl():
-    from repro.kernels.pvq_encode import pvq_encode_batch
-
-    with pytest.raises(ValueError, match="sort_impl"):
-        pvq_encode_batch(
-            jnp.ones((4, 64)), k_pulses=8, interpret=True, sort_impl="bogo"
-        )
+    rng = np.random.default_rng(n)
+    frac = (rng.integers(0, 6, (8, n)) / 8).astype(np.float32)
+    bulk = rng.integers(0, n + 1, (8, 1)).astype(np.int32)
+    got = np.asarray(_bulk_mask(jnp.asarray(frac), jnp.asarray(bulk)))
+    order = np.argsort(-frac, axis=-1, kind="stable")
+    rank = np.argsort(order, axis=-1, kind="stable")
+    np.testing.assert_array_equal(got, (rank < bulk).astype(np.float32))
 
 
 def test_pvq_encode_zero_rows():
@@ -326,3 +318,51 @@ def test_sequential_kernel_apply_matches_dequant_forward():
         pre = hp @ w_deq + params[f"layer{i}"]["bias"]
         h = jax.nn.relu(pre) if spec.activation == "relu" else pre
     np.testing.assert_allclose(np.asarray(got), np.asarray(h), rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("m,k,n,group", [(8, 512, 384, 64), (16, 1024, 256, 256)])
+def test_matmul_q_kernel_matches_ref_oracle(m, k, n, group):
+    """Kernel v3 against ``ref.pvq_matmul_q_ref`` (the oracle the chip
+    smoke uses): int8 x int8 dots are exact in int32, so only the f32
+    scale multiplies differ."""
+    from repro.kernels.pvq_matmul import pvq_matmul_q
+    from repro.kernels.ref import pvq_matmul_q_ref
+
+    kx, kw, ks = jax.random.split(jax.random.PRNGKey(m + k + n), 3)
+    x_q = jax.random.randint(kx, (m, k), -127, 128, jnp.int8)
+    a = jnp.full((m, 1), 0.01, jnp.float32)
+    w = jax.random.randint(kw, (k, n), -4, 5, jnp.int8)
+    s = jnp.abs(jax.random.normal(ks, (k // group, n))) * 0.05
+    y = pvq_matmul_q(x_q, w, s, a, group=group, interpret=True)
+    ref = pvq_matmul_q_ref(x_q, w, s, a, group=group)
+    assert float(jnp.max(jnp.abs(y - ref)) / jnp.max(jnp.abs(ref))) <= 1e-4
+
+
+@pytest.mark.parametrize("bh,m,s", [(6, 3, 96), (2, 24, 200)])
+def test_attn_q_kernel_matches_ref_oracle(bh, m, s):
+    """Kernel v4 against ``ref.pvq_attn_q_ref``: the running max and
+    denominator to f32 rounding, the output within the int8 requantization
+    of the probabilities (3% of max |V|), and an empty row (kv_len 0)
+    giving m = -1e30, l = 0."""
+    from repro.kernels.pvq_matmul import pvq_attn_q
+    from repro.kernels.ref import pvq_attn_q_ref
+
+    hd, group = 64, 32
+    ks = jax.random.split(jax.random.PRNGKey(bh * m + s), 7)
+    q = jax.random.randint(ks[0], (bh, m, hd), -127, 128, jnp.int8)
+    a = jnp.abs(jax.random.normal(ks[1], (bh, m, 1))) * 0.02
+    kp = jax.random.randint(ks[2], (bh, s, hd), -6, 7, jnp.int8)
+    vp = jax.random.randint(ks[3], (bh, s, hd), -6, 7, jnp.int8)
+    k_s = jnp.abs(jax.random.normal(ks[4], (bh, s, hd // group))) * 0.1
+    v_s = jnp.abs(jax.random.normal(ks[5], (bh, s, hd // group))) * 0.1
+    kv_len = jax.random.randint(ks[6], (bh,), 1, s + 1).at[0].set(0)
+    args = (q, a, kp, k_s, vp, v_s, kv_len)
+    acc, mx, l = pvq_attn_q(*args, group=group, sm_scale=0.125, interpret=True)
+    r_acc, r_mx, r_l = pvq_attn_q_ref(*args, group=group, sm_scale=0.125)
+    np.testing.assert_allclose(np.asarray(mx), np.asarray(r_mx), rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(l), np.asarray(r_l), rtol=1e-4, atol=0)
+    assert float(l[0].max()) == 0.0 and float(mx[0].max()) == np.float32(-1e30)
+    v_max = float(jnp.max(jnp.abs(vp * jnp.repeat(v_s, group, -1))))
+    live = slice(1, None)
+    err = np.abs(acc[live] / l[live] - r_acc[live] / r_l[live]).max() / v_max
+    assert err <= 3e-2, err
