@@ -183,6 +183,17 @@ def materialize(leaf: Any, dtype=None) -> Array:
 # ---------------------------------------------------------------------------
 
 
+#: ``jax.named_scope`` around every PVQ encode of KV pages and its scatter
+#: into the page pool.  It reaches the compiled ops' ``op_name`` metadata
+#: only, so a device trace's ops are put under it by instruction name
+#: (``repro.runtime.telemetry.hlo_op_scopes``), whatever the encode's ops are.
+KV_ENCODE_SCOPE = "kv_page_encode"
+
+
+def _kv_encode_scope():
+    return jax.named_scope(KV_ENCODE_SCOPE)
+
+
 def _kv_encode_planes(x: Array, group: int, k: int) -> Tuple[Array, Array]:
     """PVQ-encode the head dim of ``x (..., hd)`` in ``hd // group`` groups.
 
@@ -689,9 +700,9 @@ class PagedKV:
             )
 
         pools = (self.k_pages, self.k_page_scales, self.v_pages, self.v_page_scales)
-        kpg, ksg, vpg, vsg = jax.lax.cond(
-            jnp.any(completes), encode, lambda p: p, pools
-        )
+        any_completes = jnp.any(completes)
+        with _kv_encode_scope():
+            kpg, ksg, vpg, vsg = jax.lax.cond(any_completes, encode, lambda p: p, pools)
         return dataclasses.replace(
             self, k_pages=kpg, k_page_scales=ksg, v_pages=vpg, v_page_scales=vsg,
             tail_k=tail_k, tail_v=tail_v,
@@ -759,8 +770,9 @@ class PagedKV:
         nb = kf.shape[0] // page
         kb = kf.reshape(nb, page, kf.shape[-2], kf.shape[-1])
         vb = vf.reshape(nb, page, vf.shape[-2], vf.shape[-1])
-        pk, sk = _kv_encode_planes(kb, self.group, self.k)
-        pv, sv = _kv_encode_planes(vb, self.group, self.k)
+        with _kv_encode_scope():
+            pk, sk = _kv_encode_planes(kb, self.group, self.k)
+            pv, sv = _kv_encode_planes(vb, self.group, self.k)
         ids = jnp.asarray(page_ids, jnp.int32)
 
         # exact tail: the block window starting at packed_end(real_len),
@@ -774,12 +786,15 @@ class PagedKV:
         tk = jax.lax.dynamic_slice_in_dim(kf, off, page, axis=0).astype(tdt)
         tv = jax.lax.dynamic_slice_in_dim(vf, off, page, axis=0).astype(tdt)
         upd = jax.lax.dynamic_update_slice_in_dim
+        with _kv_encode_scope():
+            pools = dict(
+                k_pages=self.k_pages.at[ids].set(pk),
+                k_page_scales=self.k_page_scales.at[ids].set(sk),
+                v_pages=self.v_pages.at[ids].set(pv),
+                v_page_scales=self.v_page_scales.at[ids].set(sv),
+            )
         return dataclasses.replace(
-            self,
-            k_pages=self.k_pages.at[ids].set(pk),
-            k_page_scales=self.k_page_scales.at[ids].set(sk),
-            v_pages=self.v_pages.at[ids].set(pv),
-            v_page_scales=self.v_page_scales.at[ids].set(sv),
+            self, **pools,
             tail_k=upd(self.tail_k, tk[None], slot, axis=0),
             tail_v=upd(self.tail_v, tv[None], slot, axis=0),
         )

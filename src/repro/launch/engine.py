@@ -65,6 +65,20 @@ from repro.runtime import obs
 from repro.runtime.telemetry import Histogram
 
 
+#: ``PVQEngine.stats`` keys and the registry counters ``publish_stats``
+#: writes them under (``serve --metrics-out`` publishes them at exit)
+STAT_METRICS = {
+    "steps": "engine.decode_steps",
+    "decode_tokens": "engine.decode_tokens",
+    "admissions": "engine.admissions",
+    "evictions": "engine.evictions",
+    "kv_pages_completed": "engine.kv_pages_completed",
+    "prefix_hits": "prefix_cache.hit",
+    "prefix_misses": "prefix_cache.miss",
+    "prefix_pages_shared": "prefix_cache.pages_shared",
+}
+
+
 def bucket_len(n: int, multiple: int) -> int:
     """Round ``n`` up to a positive multiple — the static-shape buckets
     that keep XLA compile counts bounded (shared by the engine's prefill
@@ -388,6 +402,7 @@ class PVQEngine:
         prefill_chunk: Optional[int] = None,
         prefill_batch: int = 1,
         prefix_cache: bool = True,
+        kv_probes: int = 0,
     ):
         kvq = default_kv_quant()
         if kvq is None:
@@ -437,8 +452,11 @@ class PVQEngine:
         self.trace_counts: Dict[str, int] = {
             "decode": 0, "prefill": 0, "graft": 0, "chunk": 0,
         }
+        # the engine's one set of counts; publish_stats() hands them to the
+        # registry under the names in STAT_METRICS
         self.stats: Dict[str, int] = {
             "steps": 0, "active_slot_steps": 0, "evictions": 0, "decode_tokens": 0,
+            "admissions": 0, "kv_pages_completed": 0,
             "prefill_batches": 0, "prefill_rows": 0, "chunks": 0,
             "prefix_hits": 0, "prefix_misses": 0, "prefix_pages_shared": 0,
         }
@@ -452,9 +470,10 @@ class PVQEngine:
         self._itl_decode_s: List[float] = []
         self._itl_with_prefill_s: List[float] = []
         # sampled KV quality probes: the graft's in-graph encode cannot
-        # probe itself (traced), so the first few admissions re-encode one
-        # prefilled page eagerly when the registry is on
-        self._kv_probe_budget = 8
+        # probe itself (traced), so the first kv_probes admissions re-encode
+        # one prefilled page eagerly when the registry is on (an eager
+        # encode and a device_get each: serve --metrics-out asks for them)
+        self._kv_probe_budget = int(kv_probes)
 
     # ------------------------------------------------------------- capacity
 
@@ -599,17 +618,18 @@ class PVQEngine:
         chunked state machine instead (their prefill streams through
         :meth:`_prefill_step`, interleaved with decode steps)."""
         admitted = 0
-        while self.pending:
-            req = self.pending[0]
-            self.validate(req)
-            ctx = self._ctx_tokens(req)
-            if self._chunk_routed(ctx):
-                n = self._admit_chunked(req, ctx, t_now)
-            else:
-                n = self._admit_batch(t_now)
-            if not n:
-                break
-            admitted += n
+        with obs.span("engine/admit"):
+            while self.pending:
+                req = self.pending[0]
+                self.validate(req)
+                ctx = self._ctx_tokens(req)
+                if self._chunk_routed(ctx):
+                    n = self._admit_chunked(req, ctx, t_now)
+                else:
+                    n = self._admit_batch(t_now)
+                if not n:
+                    break
+                admitted += n
         return admitted
 
     # ------------------------------------------------- chunked admission
@@ -651,21 +671,11 @@ class PVQEngine:
         self._page_table[slot, :] = self.alloc.trash
         self._page_table[slot, :n_full] = st.pages
         self.pending.popleft()
+        self.stats["admissions"] += 1
         self.stats["prefix_hits"] += len(hits)
         self.stats["prefix_pages_shared"] += len(hits)
         if self.prefix_cache and len(hits) < max_hit:
             self.stats["prefix_misses"] += 1
-        if obs.enabled():
-            obs.counter("engine.admissions").inc()
-            if hits:
-                obs.counter("prefix_cache.hit").add(len(hits))
-                obs.counter("prefix_cache.pages_shared").add(len(hits))
-            if self.prefix_cache and len(hits) < max_hit:
-                obs.counter("prefix_cache.miss").inc()
-            obs.event("engine/admit", args={
-                "rid": req.rid, "ctx": plen, "chunked": 1,
-                "prefix_pages": len(hits),
-            })
         return 1
 
     # ------------------------------------------------- batched admission
@@ -738,6 +748,7 @@ class PVQEngine:
             self.cache = self._graft(self.cache, pre, slots_arr, ids_arr, real)
         tok_host = np.asarray(jax.device_get(tok0))
         dt = time.perf_counter() - t0
+        self.stats["admissions"] += len(rows)
         self.stats["prefill_batches"] += 1
         self.stats["prefill_rows"] += len(rows)
         for i, (req, ctx, slot, ids) in enumerate(rows):
@@ -768,10 +779,6 @@ class PVQEngine:
             self._admit_seq += 1
             self._page_table[slot, :] = self.alloc.trash
             self._page_table[slot, : len(ids)] = ids
-        if obs.enabled():
-            obs.counter("engine.admissions").add(len(rows))
-            for req, ctx, _, _ in rows:
-                obs.event("engine/admit", args={"rid": req.rid, "ctx": len(ctx)})
 
     # --------------------------------------------------- chunked prefill
 
@@ -891,7 +898,6 @@ class PVQEngine:
             obs.histogram("engine.chunk_wait_s").record(req.chunk_wait_s)
             if req.evictions:
                 obs.histogram("engine.evict_cost_s").record(req.evict_cost_s)
-            obs.event("engine/retire", args={"rid": req.rid})
 
     def _release(self, s: int) -> _Slot:
         st = self.slots[s]
@@ -910,16 +916,28 @@ class PVQEngine:
         st.req.evictions += 1
         st.req.evict_t = time.perf_counter()
         self.stats["evictions"] += 1
-        if obs.enabled():
-            obs.counter("engine.evictions").inc()
-            obs.event(
-                "engine/evict",
-                args={"rid": st.req.rid, "kept_tokens": len(st.req.generated)},
-            )
         # queue head: the victim resumes as soon as pages free up
         self.pending.appendleft(st.req)
 
     # ----------------------------------------------------------- decode step
+
+    def _evict_for_pages(self) -> List[Tuple[int, _Slot]]:
+        """The decoding slots, after evicting the youngest until the free
+        pages cover every slot that completes a page this step."""
+        while True:
+            active = [
+                (s, st) for s, st in enumerate(self.slots)
+                if st is not None and st.phase == "decode"
+            ]
+            if not active:
+                return active
+            needed = sum(
+                1 for _, st in active if (st.length + 1) % self.page == 0
+            )
+            if needed <= self.alloc.available:
+                return active
+            victim = max(active, key=lambda t: t[1].admit_order)[0]
+            self._evict(victim)
 
     def step(self) -> int:
         """One decode step over every active slot.  Returns the number of
@@ -931,67 +949,64 @@ class PVQEngine:
         can (guaranteed to terminate: a lone sequence never needs more
         than ``max_pages`` <= ``n_pages``).  Slots still in phase
         "prefill" neither decode nor get evicted — their pages were fully
-        reserved at admission, so they always make progress."""
-        while True:
-            active = [
-                (s, st) for s, st in enumerate(self.slots)
-                if st is not None and st.phase == "decode"
-            ]
-            if not active:
-                return 0
-            needed = sum(
-                1 for _, st in active if (st.length + 1) % self.page == 0
-            )
-            if needed <= self.alloc.available:
-                break
-            victim = max(active, key=lambda t: t[1].admit_order)[0]
-            self._evict(victim)
+        reserved at admission, so they always make progress.
 
-        tokens = np.zeros((self.n_slots, 1), np.int32)
-        pos = np.zeros((self.n_slots,), np.int32)
-        write_page = np.full((self.n_slots,), self.alloc.trash, np.int32)
-        for s, st in active:
-            tokens[s, 0] = st.req.generated[-1]
-            pos[s] = st.length
-            if (st.length + 1) % self.page == 0:
-                pid = self.alloc.alloc()
-                assert pid is not None  # reserved above
-                st.pages.append(pid)
-                self._page_table[s, st.length // self.page] = pid
-                write_page[s] = pid
-
-        # obs.NOOP when disabled: no span object, no args dict — the
-        # telemetry hook adds zero allocations to the disabled decode step
-        span = obs.NOOP
-        if obs.enabled():
-            span = obs.span("engine/decode_step", args={
-                "active": len(active), "queue": len(self.pending),
-                "free_pages": self.alloc.available,
-            })
+        With the registry on, the step is one ``engine/decode_step`` span
+        (args ``active``, ``n_slots``, ``queue``, ``free_pages`` and
+        ``pages_completed``) holding four that follow one another:
+        ``engine/decode/prepare`` (eviction, inputs, page allocation),
+        ``engine/decode/launch`` (the call of the compiled step),
+        ``engine/decode/wait`` (``device_get`` of the tokens) and
+        ``engine/decode/commit`` (tokens appended, requests retired)."""
+        if not any(st is not None and st.phase == "decode" for st in self.slots):
+            return 0
+        span = obs.span("engine/decode_step")
         with span:
-            tok_ids, self.cache = self._decode(
-                self.params, self.cache, tokens, pos,
-                self._page_table.copy(), write_page,
-            )
-            tok_host = np.asarray(jax.device_get(tok_ids))
-        self.stats["steps"] += 1
-        self.stats["active_slot_steps"] += len(active)
-        self.stats["decode_tokens"] += len(active)
-        if obs.enabled():
-            obs.counter("engine.decode_steps").inc()
-            obs.counter("engine.decode_tokens").add(len(active))
-            obs.gauge("engine.queue_depth").set(len(self.pending))
-            obs.gauge("engine.page_pool_free").set(self.alloc.available)
-            obs.gauge("engine.active_slots").set(len(active))
-            # counter-track events: perfetto renders these as time series
-            obs.trace_counter("engine.queue_depth", len(self.pending))
-            obs.trace_counter("engine.page_pool_free", self.alloc.available)
-            obs.trace_counter("engine.active_slots", len(active))
-        for s, st in active:
-            st.length += 1
-            st.req.generated.append(int(tok_host[s]))
-            if st.req.done:
-                self._retire(s)
+            with obs.span("engine/decode/prepare"):
+                active = self._evict_for_pages()
+                if not active:
+                    return 0
+                tokens = np.zeros((self.n_slots, 1), np.int32)
+                pos = np.zeros((self.n_slots,), np.int32)
+                write_page = np.full((self.n_slots,), self.alloc.trash, np.int32)
+                completed = 0
+                for s, st in active:
+                    tokens[s, 0] = st.req.generated[-1]
+                    pos[s] = st.length
+                    if (st.length + 1) % self.page == 0:
+                        pid = self.alloc.alloc()
+                        assert pid is not None  # reserved above
+                        st.pages.append(pid)
+                        self._page_table[s, st.length // self.page] = pid
+                        write_page[s] = pid
+                        completed += 1
+                self.stats["kv_pages_completed"] += completed
+            if obs.enabled():
+                span.set_metadata(
+                    active=len(active), n_slots=self.n_slots,
+                    queue=len(self.pending), free_pages=self.alloc.available,
+                    pages_completed=completed,
+                )
+            with obs.span("engine/decode/launch"):
+                tok_ids, self.cache = self._decode(
+                    self.params, self.cache, tokens, pos,
+                    self._page_table.copy(), write_page,
+                )
+            with obs.span("engine/decode/wait"):
+                tok_host = np.asarray(jax.device_get(tok_ids))
+            with obs.span("engine/decode/commit"):
+                self.stats["steps"] += 1
+                self.stats["active_slot_steps"] += len(active)
+                self.stats["decode_tokens"] += len(active)
+                if obs.enabled():
+                    obs.gauge("engine.queue_depth").set(len(self.pending))
+                    obs.gauge("engine.page_pool_free").set(self.alloc.available)
+                    obs.gauge("engine.active_slots").set(len(active))
+                for s, st in active:
+                    st.length += 1
+                    st.req.generated.append(int(tok_host[s]))
+                    if st.req.done:
+                        self._retire(s)
         return len(active)
 
     # --------------------------------------------------------------- warmup
@@ -1100,6 +1115,12 @@ class PVQEngine:
         return asyncio.run(self._run_async(list(trace), time_scale))
 
     # -------------------------------------------------------------- metrics
+
+    def publish_stats(self) -> None:
+        """Add ``stats`` to the registry's counters under the names in
+        :data:`STAT_METRICS` (once, when a run ends)."""
+        for key, name in STAT_METRICS.items():
+            obs.counter(name).add(self.stats[key])
 
     def report(self, wall_s: float) -> Dict[str, Any]:
         done = self.finished

@@ -54,7 +54,7 @@ from repro.core.packed import expert_leaves, packed_stats, quantize_params
 from repro.core.quantize import QuantPolicy, quantize_tree, total_bits
 from repro.launch.engine import bucket_len
 from repro.nn.models import build_model
-from repro.runtime import obs
+from repro.runtime import obs, telemetry
 from repro.runtime.caches import enable_compile_cache
 
 # Actual XLA trace counts of the shared decode step (incremented by a
@@ -396,9 +396,11 @@ def run(argv: Optional[Sequence[str]] = None) -> Tuple[int, dict]:
         "--metrics-out",
         default=None,
         metavar="DIR",
-        help="enable the process telemetry registry (repro.runtime.obs) and "
-        "write metrics.jsonl + a perfetto-loadable trace.json into DIR on "
-        "exit (every exit path, gate failures included)",
+        help="enable the process telemetry registry (repro.runtime.obs), run "
+        "under the JAX profiler (trace and perfetto_trace.json.gz under "
+        "DIR/plugins/profile/, engine spans and device ops on one clock) and "
+        "write metrics.jsonl into DIR on exit (every exit path, gate "
+        "failures included)",
     )
     args = ap.parse_args(argv)
     if args.act_int8 and not (args.pvq or args.artifact):
@@ -412,13 +414,17 @@ def run(argv: Optional[Sequence[str]] = None) -> Tuple[int, dict]:
                  "block); it requires --kv-pvq")
 
     enable_compile_cache()
-    if args.metrics_out:
-        obs.set_enabled(True)
-    try:
+    if not args.metrics_out:
         return _serve(args)
+    obs.set_enabled(True)
+    try:
+        with jax.profiler.trace(
+            args.metrics_out, create_perfetto_trace=True,
+            profiler_options=telemetry.profiler_options(),
+        ):
+            return _serve(args)
     finally:
-        if args.metrics_out:
-            obs.write(args.metrics_out)
+        obs.write(args.metrics_out)
 
 
 def _probe_act_rows(params) -> None:
@@ -684,6 +690,7 @@ def _serve(args) -> Tuple[int, dict]:
             prefill_chunk=args.prefill_chunk,
             prefill_batch=args.prefill_batch,
             prefix_cache=not args.no_prefix_cache,
+            kv_probes=8 if args.metrics_out else 0,
         )
         t0 = time.time()
         eng.warmup(prompt_lens=[len(r.prompt) for r in trace])
@@ -692,7 +699,13 @@ def _serve(args) -> Tuple[int, dict]:
         report["engine_decode_mosaic_kernels"] = eng.decode_hlo().count(
             "tpu_custom_call"
         )
-        res = eng.run(trace)
+        try:
+            res = eng.run(trace)
+        finally:
+            # before run()'s own finally writes metrics.jsonl, so the
+            # counts reach it when the run raises too
+            if obs.enabled():
+                eng.publish_stats()
         outputs = res.pop("outputs")
         report["arch"] = cfg.name
         report.update({f"engine_{k}": v for k, v in res.items()})

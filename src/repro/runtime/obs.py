@@ -6,9 +6,9 @@ process registry::
     from repro.runtime import obs
 
     if obs.enabled():                      # hot paths guard first
-        obs.counter("engine.decode_steps").inc()
-        with obs.span("engine/decode_step", args={"active": n}):
-            ...
+        obs.gauge("engine.queue_depth").set(n)
+    with obs.span("engine/admit"):         # a profiler annotation
+        ...
 
 Every accessor delegates to the module registry; when it is disabled
 (the default) ``counter``/``gauge``/``histogram``/``span`` return the
@@ -54,14 +54,6 @@ def span(name: str, args: Optional[dict] = None):
     return telemetry.get_registry().span(name, args)
 
 
-def trace_counter(name: str, value: float) -> None:
-    telemetry.get_registry().trace_counter(name, value)
-
-
-def event(name: str, args: Optional[dict] = None) -> None:
-    telemetry.get_registry().event(name, args)
-
-
 def write(outdir: str) -> Dict[str, str]:
-    """Export ``metrics.jsonl`` + ``trace.json`` into ``outdir``."""
+    """Export ``metrics.jsonl`` into ``outdir``."""
     return telemetry.get_registry().write(outdir)

@@ -1,4 +1,4 @@
-"""Process-wide observability: metrics registry + span tracing.
+"""Process-wide observability: metrics registry + profiler spans.
 
 One registry serves the whole serve stack — the continuous-batching
 engine, the fixed-batch ``serve.py`` legs, the kernel autotuner, the
@@ -23,20 +23,25 @@ optional flat ``{str: str}`` dict (e.g. ``{"codec": "golomb"}``).
 
 Tracing
 -------
-``registry.span(name, args=...)`` is a context manager recording a
-Chrome trace-event *complete* event (``ph: "X"``) with microsecond
-timestamps; ``trace_counter(name, value)`` records a counter-track event
-(``ph: "C"``) that perfetto renders as a time series (the engine emits
-queue-depth and page-pool-free this way every decode step).
-``export_chrome_trace`` writes a ``trace.json`` loadable in
-https://ui.perfetto.dev (open the file directly) or ``chrome://tracing``.
+``registry.span(name, args=...)`` enters a ``jax.profiler.TraceAnnotation``
+when the registry is enabled, so a span lands in the JAX profiler's own
+trace, on the same clock as the device's ops, with ``args`` as its stats
+(``span.set_metadata(**args)`` adds more before the span closes).  The
+profiler is the only trace store: ``serve --metrics-out DIR`` runs the
+serve under ``jax.profiler.trace(DIR, create_perfetto_trace=True,
+profiler_options=profiler_options())``, and :func:`read_host_spans`
+reads the spans back from the ``.xplane.pb`` with ``ProfileData``.
+:func:`hlo_op_scopes` maps a compiled program's instructions to the
+``jax.named_scope`` path in their metadata, which is how a trace's op
+events (named by instruction) are put under a scope such as
+``kv_page_encode``.
 
 Hot-path contract
 -----------------
 A **disabled** registry is a true no-op: ``counter()``/``gauge()``/
 ``histogram()``/``span()`` all return the shared :data:`NOOP` singleton
 and allocate nothing.  Call sites on hot loops additionally guard with
-``obs.enabled()`` so not even argument tuples are built.  Nothing in
+``obs.enabled()`` so not even argument dicts are built.  Nothing in
 this module is ever traced into a jit body — instrumentation lives in
 host-side driver loops, and the eager-only quantization probes bail out
 when handed a tracer.
@@ -46,27 +51,29 @@ Export
 * ``export_metrics_jsonl(path)`` — one JSON object per line, schema
   ``repro-metrics-v1`` (see :data:`METRICS_SCHEMA`); round-trips through
   :func:`read_metrics_jsonl` / :func:`validate_metrics_jsonl`.
-* ``export_chrome_trace(path)`` — ``{"traceEvents": [...]}`` JSON;
-  validated by :func:`validate_chrome_trace`.
-* ``write(outdir)`` — both files into a directory (the
-  ``serve --metrics-out DIR`` exit hook).
+* ``write(outdir)`` — ``metrics.jsonl`` into a directory (the
+  ``serve --metrics-out DIR`` exit hook; the profiler writes the trace
+  under ``DIR/plugins/profile/``).
 
-``python -m repro.runtime.telemetry --validate DIR`` runs both
-validators (the CI schema gate); ``--require-engine`` additionally
-asserts the engine spans/gauges/autotune counters/quant probes the
-serve smoke must emit.
+``python -m repro.runtime.telemetry --validate DIR`` checks
+``metrics.jsonl`` (the CI schema gate); ``--require-engine`` also reads
+the profiler trace under ``DIR`` and asserts the engine spans there and
+the gauges/autotune counters/quant probes the serve smoke must emit.
 """
 
 from __future__ import annotations
 
+import glob
 import json
 import os
 import random
+import re
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import ProfileData, ProfileOptions, TraceAnnotation
 
 METRICS_SCHEMA = "repro-metrics-v1"
 
@@ -220,6 +227,9 @@ class _Noop:
     def record_many(self, values) -> None:
         pass
 
+    def set_metadata(self, **args) -> None:
+        pass
+
     def __enter__(self) -> "_Noop":
         return self
 
@@ -228,32 +238,6 @@ class _Noop:
 
 
 NOOP = _Noop()
-
-
-# ---------------------------------------------------------------------------
-# spans
-# ---------------------------------------------------------------------------
-
-
-class _Span:
-    """Context manager recording one Chrome complete event (``ph: X``)."""
-
-    __slots__ = ("_reg", "name", "args", "_t0")
-
-    def __init__(self, reg: "MetricsRegistry", name: str, args: Optional[dict]):
-        self._reg = reg
-        self.name = name
-        self.args = args
-        self._t0 = 0.0
-
-    def __enter__(self) -> "_Span":
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        t1 = time.perf_counter()
-        self._reg._record_span(self.name, self._t0, t1, self.args)
-        return False
 
 
 # ---------------------------------------------------------------------------
@@ -268,7 +252,7 @@ def _key(name: str, labels: Optional[Dict[str, str]]) -> Tuple:
 
 
 class MetricsRegistry:
-    """Process-wide metric + trace store.
+    """Process-wide metric store; spans go to the JAX profiler.
 
     ``enabled=False`` (the default for the module registry) turns every
     accessor into a :data:`NOOP` return — zero instrument allocation,
@@ -281,9 +265,6 @@ class MetricsRegistry:
         self._counters: Dict[Tuple, Counter] = {}
         self._gauges: Dict[Tuple, Gauge] = {}
         self._histograms: Dict[Tuple, Histogram] = {}
-        self._events: List[Dict[str, Any]] = []
-        self._t0 = time.perf_counter()
-        self._pid = os.getpid()
 
     # ------------------------------------------------------------ lifecycle
 
@@ -292,8 +273,6 @@ class MetricsRegistry:
             self._counters.clear()
             self._gauges.clear()
             self._histograms.clear()
-            self._events.clear()
-            self._t0 = time.perf_counter()
 
     # ----------------------------------------------------------- instruments
 
@@ -330,48 +309,12 @@ class MetricsRegistry:
     # --------------------------------------------------------------- tracing
 
     def span(self, name: str, args: Optional[dict] = None):
+        """A ``jax.profiler.TraceAnnotation`` carrying ``args`` as its
+        stats; :data:`NOOP` when disabled.  Outside a profiler session the
+        annotation records nothing."""
         if not self.enabled:
             return NOOP
-        return _Span(self, name, args)
-
-    def _record_span(self, name: str, t0: float, t1: float, args) -> None:
-        ev = {
-            "name": name, "ph": "X", "pid": self._pid,
-            "tid": threading.get_ident() & 0xFFFF,
-            "ts": round(1e6 * (t0 - self._t0), 1),
-            "dur": round(1e6 * (t1 - t0), 1),
-        }
-        if args:
-            ev["args"] = dict(args)
-        with self._lock:
-            self._events.append(ev)
-
-    def trace_counter(self, name: str, value: float) -> None:
-        """Counter-track event (``ph: C``): a per-step time series that
-        perfetto renders as its own track (queue depth, free pages, ...)."""
-        if not self.enabled:
-            return
-        ev = {
-            "name": name, "ph": "C", "pid": self._pid,
-            "ts": round(1e6 * (time.perf_counter() - self._t0), 1),
-            "args": {"value": float(value)},
-        }
-        with self._lock:
-            self._events.append(ev)
-
-    def event(self, name: str, args: Optional[dict] = None) -> None:
-        """Instant event (``ph: i``) — admissions, evictions, retires."""
-        if not self.enabled:
-            return
-        ev = {
-            "name": name, "ph": "i", "s": "p", "pid": self._pid,
-            "tid": threading.get_ident() & 0xFFFF,
-            "ts": round(1e6 * (time.perf_counter() - self._t0), 1),
-        }
-        if args:
-            ev["args"] = dict(args)
-        with self._lock:
-            self._events.append(ev)
+        return TraceAnnotation(name, **(args or {}))
 
     # ---------------------------------------------------------------- export
 
@@ -390,31 +333,18 @@ class MetricsRegistry:
             out.append(rec)
         return out
 
-    def chrome_trace(self) -> Dict[str, Any]:
-        with self._lock:
-            events = list(self._events)
-        return {"displayTimeUnit": "ms", "traceEvents": events}
-
     def export_metrics_jsonl(self, path: str) -> str:
         with open(path, "w") as f:
             for rec in self.snapshot():
                 f.write(json.dumps(rec) + "\n")
         return str(path)
 
-    def export_chrome_trace(self, path: str) -> str:
-        with open(path, "w") as f:
-            json.dump(self.chrome_trace(), f)
-        return str(path)
-
     def write(self, outdir: str) -> Dict[str, str]:
-        """Write ``metrics.jsonl`` + ``trace.json`` into ``outdir``."""
+        """Write ``metrics.jsonl`` into ``outdir``."""
         os.makedirs(outdir, exist_ok=True)
         return {
             "metrics": self.export_metrics_jsonl(
                 os.path.join(outdir, "metrics.jsonl")
-            ),
-            "trace": self.export_chrome_trace(
-                os.path.join(outdir, "trace.json")
             ),
         }
 
@@ -521,29 +451,82 @@ def validate_metrics_jsonl(path: str) -> List[Dict[str, Any]]:
     return recs
 
 
-def validate_chrome_trace(path: str) -> List[Dict[str, Any]]:
-    """Check a trace file is perfetto-loadable trace-event JSON."""
-    with open(path) as f:
-        doc = json.load(f)
-    events = doc.get("traceEvents")
-    if not isinstance(events, list):
-        raise ValueError(f"{path}: traceEvents must be a list")
-    for i, ev in enumerate(events):
-        where = f"{path}: traceEvents[{i}]"
-        if not isinstance(ev.get("name"), str):
-            raise ValueError(f"{where}: missing event name")
-        if ev.get("ph") not in ("X", "C", "i", "B", "E", "M"):
-            raise ValueError(f"{where}: bad phase {ev.get('ph')!r}")
-        if not isinstance(ev.get("ts"), (int, float)):
-            raise ValueError(f"{where}: missing ts")
-        if ev["ph"] == "X" and not isinstance(ev.get("dur"), (int, float)):
-            raise ValueError(f"{where}: complete event missing dur")
-    return events
+# ---------------------------------------------------------------------------
+# the profiler trace
+# ---------------------------------------------------------------------------
 
 
-#: names the engine serve smoke must cover (ISSUE-8 acceptance: engine
-#: spans, page-pool/queue gauges, autotune counters, quant-quality probes)
-ENGINE_REQUIRED_SPANS = ("engine/prefill", "engine/graft", "engine/decode_step")
+def profiler_options() -> ProfileOptions:
+    """Device ops and host annotations only: no Python call tracing, no
+    runtime events and no HLO protos, which would swell the trace
+    manyfold and slow the host."""
+    opts = ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    opts.enable_hlo_proto = False
+    return opts
+
+
+def find_profile(path: str) -> str:
+    """The newest ``.xplane.pb`` under ``path`` (or ``path`` itself)."""
+    if os.path.isfile(path):
+        return path
+    found = glob.glob(os.path.join(path, "**", "*.xplane.pb"), recursive=True)
+    if not found:
+        raise ValueError(f"{path}: no profiler trace (*.xplane.pb)")
+    return max(found, key=os.path.getmtime)
+
+
+def read_host_spans(path: str, prefix: str = "engine/") -> List[Dict[str, Any]]:
+    """Host annotations named ``prefix...`` in a profiler trace, by start:
+    ``{"name", "start", "dur", "args"}`` with times in ns on the trace's
+    clock (the device ops' clock) and the span's args as ``args``."""
+    out = []
+    for plane in ProfileData.from_file(find_profile(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(prefix):
+                    out.append({
+                        "name": ev.name, "start": float(ev.start_ns),
+                        "dur": float(ev.duration_ns), "args": dict(ev.stats),
+                    })
+    return sorted(out, key=lambda e: e["start"])
+
+
+_HLO_OP = re.compile(r'^\s*(?:ROOT\s+)?%?([^\s=]+) = .*?metadata=\{[^}]*?op_name="([^"]*)"')
+
+
+def hlo_op_scopes(text: str) -> Dict[str, str]:
+    """Instruction name -> ``op_name`` metadata (``jit(f)/scope/.../op``)
+    in a compiled program's HLO text (``Compiled.as_text()``).  A device
+    trace names each op by its instruction, so this puts trace events
+    under the ``jax.named_scope`` that made them."""
+    out = {}
+    for line in text.splitlines():
+        m = _HLO_OP.match(line)
+        if m:
+            out[m.group(1)] = m.group(2)
+    return out
+
+
+def in_scope(op_name: str, scope: str) -> bool:
+    """Whether an ``op_name`` path lies under ``scope``: one of its parts is
+    the scope, or the scope under a transform (``vmap(kv_page_encode)``)."""
+    return any(
+        part == scope or part.endswith(f"({scope})") for part in op_name.split("/")
+    )
+
+
+#: what the engine serve smoke must cover (``--require-engine``): engine
+#: spans in the profiler trace; page-pool/queue gauges, autotune counters
+#: and quant-quality probes in metrics.jsonl
+ENGINE_REQUIRED_SPANS = (
+    "engine/admit", "engine/prefill", "engine/graft", "engine/decode_step",
+    "engine/decode/prepare", "engine/decode/launch", "engine/decode/wait",
+    "engine/decode/commit",
+)
 ENGINE_REQUIRED_METRICS = (
     "engine.page_pool_free", "engine.queue_depth",
     "autotune.lookups", "quant.weight_snr_db", "quant.kv_snr_db",
@@ -551,19 +534,20 @@ ENGINE_REQUIRED_METRICS = (
 
 
 def validate_dir(outdir: str, *, require_engine: bool = False) -> Dict[str, int]:
-    """Validate ``metrics.jsonl`` + ``trace.json`` in ``outdir``."""
+    """Validate ``metrics.jsonl`` in ``outdir``; with ``require_engine``,
+    also require the engine serve-smoke metrics there and the engine spans
+    in the profiler trace under ``outdir``."""
     recs = validate_metrics_jsonl(os.path.join(outdir, "metrics.jsonl"))
-    events = validate_chrome_trace(os.path.join(outdir, "trace.json"))
-    if require_engine:
-        names = {r["name"] for r in recs}
-        missing = [m for m in ENGINE_REQUIRED_METRICS if m not in names]
-        span_names = {e["name"] for e in events}
-        missing += [s for s in ENGINE_REQUIRED_SPANS if s not in span_names]
-        if missing:
-            raise ValueError(
-                f"{outdir}: engine telemetry incomplete, missing {missing}"
-            )
-    return {"metrics": len(recs), "trace_events": len(events)}
+    if not require_engine:
+        return {"metrics": len(recs)}
+    spans = read_host_spans(outdir)
+    names = {r["name"] for r in recs}
+    missing = [m for m in ENGINE_REQUIRED_METRICS if m not in names]
+    span_names = {e["name"] for e in spans}
+    missing += [s for s in ENGINE_REQUIRED_SPANS if s not in span_names]
+    if missing:
+        raise ValueError(f"{outdir}: engine telemetry incomplete, missing {missing}")
+    return {"metrics": len(recs), "engine_spans": len(spans)}
 
 
 def _main() -> int:
@@ -571,10 +555,11 @@ def _main() -> int:
 
     ap = argparse.ArgumentParser(description="validate telemetry exports")
     ap.add_argument("--validate", metavar="DIR", required=True,
-                    help="directory holding metrics.jsonl + trace.json")
+                    help="directory holding metrics.jsonl and the profiler "
+                    "trace (serve --metrics-out DIR)")
     ap.add_argument("--require-engine", action="store_true",
                     help="additionally require the engine serve-smoke "
-                    "span/metric coverage")
+                    "spans (in the profiler trace) and metrics")
     args = ap.parse_args()
     counts = validate_dir(args.validate, require_engine=args.require_engine)
     print(json.dumps({"ok": True, "dir": args.validate, **counts}))

@@ -338,45 +338,233 @@ def test_generate_decode_compiles_once_per_bucket(served):
 
 
 # ---------------------------------------------------------------------------
-# Engine telemetry: spans/gauges land in the registry, report gains the
-# queue-wait / eviction-cost columns
+# Engine telemetry: spans on the profiler's clock, stats published under
+# their metric names, the KV page encode under one named scope
 # ---------------------------------------------------------------------------
 
 
-def test_engine_telemetry_spans_gauges_and_report_fields(served, tmp_path):
+@pytest.fixture(scope="module")
+def traced_run(served, tmp_path_factory):
+    """A tiny engine served with the registry on, under the JAX profiler."""
     from repro.runtime import obs, telemetry
 
     cfg, model, params = served
+    outdir = tmp_path_factory.mktemp("traced_run")
     prev = obs.set_enabled(True)
     obs.registry().clear()
     try:
         with kv_quant_scope(KVQ):
             trace = poisson_trace(
                 4, rate=0.0, vocab=cfg.vocab_size, prompt_lens=(4, 10),
-                max_new=4, seed=13,
+                max_new=8, seed=13,
             )
             eng = PVQEngine(model, params, n_slots=2, max_len=24)
-            res = eng.run(trace)
-        # report: queue-wait + per-request eviction-cost accounting
-        for key in ("queue_wait_p50_s", "queue_wait_p99_s",
-                    "eviction_cost_total_s", "eviction_cost_p50_s"):
-            assert key in res, key
-        assert res["queue_wait_p50_s"] >= 0.0
-        files = obs.registry().write(str(tmp_path))
-        recs = telemetry.validate_metrics_jsonl(files["metrics"])
-        names = {r["name"] for r in recs}
-        assert {"engine.decode_steps", "engine.queue_depth",
-                "engine.page_pool_free", "engine.admissions",
-                "engine.request_latency_s", "engine.queue_wait_s",
-                "engine.prefill_compute_s", "engine.chunk_wait_s"} <= names
-        by_name = {r["name"]: r for r in recs if not r["labels"]}
-        assert by_name["engine.admissions"]["value"] == 4
-        assert by_name["engine.request_latency_s"]["count"] == 4
-        events = telemetry.validate_chrome_trace(files["trace"])
-        span_names = {e["name"] for e in events}
-        assert set(telemetry.ENGINE_REQUIRED_SPANS) <= span_names
-        # per-step counter tracks for the perfetto time series
-        assert "engine.queue_depth" in {e["name"] for e in events if e["ph"] == "C"}
+            with jax.profiler.trace(str(outdir), profiler_options=telemetry.profiler_options()):
+                res = eng.run(trace)
+            eng.publish_stats()
+        files = obs.registry().write(str(outdir))
+    finally:
+        obs.set_enabled(prev)
+        obs.registry().clear()
+    spans = telemetry.read_host_spans(str(outdir))
+    return {"eng": eng, "trace": trace, "res": res, "files": files, "spans": spans,
+            "outdir": str(outdir)}
+
+
+def test_engine_telemetry_spans_gauges_and_report_fields(traced_run):
+    from repro.launch.engine import STAT_METRICS
+    from repro.runtime import telemetry
+
+    res, eng = traced_run["res"], traced_run["eng"]
+    # report: queue-wait + per-request eviction-cost accounting
+    for key in ("queue_wait_p50_s", "queue_wait_p99_s",
+                "eviction_cost_total_s", "eviction_cost_p50_s"):
+        assert key in res, key
+    assert res["queue_wait_p50_s"] >= 0.0
+    recs = telemetry.validate_metrics_jsonl(traced_run["files"]["metrics"])
+    names = {r["name"] for r in recs}
+    assert {"engine.decode_steps", "engine.queue_depth",
+            "engine.page_pool_free", "engine.admissions",
+            "engine.kv_pages_completed", "prefix_cache.hit",
+            "engine.request_latency_s", "engine.queue_wait_s",
+            "engine.prefill_compute_s", "engine.chunk_wait_s"} <= names
+    by_name = {r["name"]: r for r in recs if not r["labels"]}
+    assert by_name["engine.admissions"]["value"] == 4
+    assert by_name["engine.request_latency_s"]["count"] == 4
+    # the published counters are the engine's stats, counted once
+    for key, name in STAT_METRICS.items():
+        assert by_name[name]["value"] == eng.stats[key], name
+    # every engine span in the profiler's trace (kv quality probes are
+    # serve --metrics-out's, so that metric is not asked of a bare engine)
+    span_names = {e["name"] for e in traced_run["spans"]}
+    assert set(telemetry.ENGINE_REQUIRED_SPANS) <= span_names
+    assert "quant.kv_snr_db" not in names
+
+
+def test_engine_decode_spans_nest_and_carry_args(traced_run):
+    """Each ``engine/decode_step`` holds prepare, launch, wait and commit in
+    that order, inside it and not overlapping, and carries its args."""
+    spans, eng = traced_run["spans"], traced_run["eng"]
+    steps = [s for s in spans if s["name"] == "engine/decode_step"]
+    kids = [s for s in spans if s["name"].startswith("engine/decode/")]
+    assert len(steps) == eng.stats["steps"] and len(kids) == 4 * len(steps)
+    order = ["engine/decode/prepare", "engine/decode/launch",
+             "engine/decode/wait", "engine/decode/commit"]
+    for st in steps:
+        end = st["start"] + st["dur"]
+        inside = [k for k in kids if st["start"] <= k["start"] < end]
+        assert [k["name"] for k in inside] == order
+        for a, b in zip(inside, inside[1:]):
+            assert a["start"] + a["dur"] <= b["start"]
+        assert inside[-1]["start"] + inside[-1]["dur"] <= end
+        args = st["args"]
+        assert set(args) == {"active", "n_slots", "queue", "free_pages", "pages_completed"}
+        assert 1 <= args["active"] <= args["n_slots"] == eng.n_slots
+    assert sum(s["args"]["active"] for s in steps) == eng.stats["decode_tokens"]
+    assert sum(s["args"]["pages_completed"] for s in steps) == eng.stats["kv_pages_completed"]
+
+
+def test_kv_pages_completed_counts_pages_decode_steps_complete(traced_run):
+    """A decode step writing position ``p`` completes a page when
+    ``(p + 1) % page == 0``; with no eviction each request writes
+    positions ``len(prompt) .. len(prompt) + max_new - 2``."""
+    eng, trace = traced_run["eng"], traced_run["trace"]
+    assert eng.stats["evictions"] == 0
+    page = KVQ.block
+    want = sum(
+        1 for r in trace
+        for p in range(len(r.prompt), len(r.prompt) + r.max_new_tokens - 1)
+        if (p + 1) % page == 0
+    )
+    assert want > 0 and eng.stats["kv_pages_completed"] == want
+
+
+def _hlo_computations(text):
+    """Computation name -> [(instruction, opcode, the rest of its line)]."""
+    import re
+
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+            continue
+        inst = re.match(r"^\s*(?:ROOT )?%(\S+) = \S.*? ([a-z][\w-]*)\((.*)$", line)
+        if inst and cur is not None:
+            cur.append(inst.groups())
+    return comps
+
+
+def _called(rest):
+    import re
+
+    out = re.findall(r"(?:condition|body|to_apply)=%([\w.-]+)", rest)
+    m = re.search(r"branch_computations=\{([^}]*)\}", rest)
+    if m:
+        out += [c.strip().lstrip("%") for c in m.group(1).split(",")]
+    return out
+
+
+def _decode_text(eng):
+    z = np.zeros((eng.n_slots,), np.int32)
+    wp = np.full((eng.n_slots,), eng.alloc.trash, np.int32)
+    return eng._decode.lower(
+        eng.params, eng.cache, z[:, None], z, eng._page_table.copy(), wp
+    ).compile().as_text()
+
+
+def test_kv_page_encode_scope_covers_the_encode(served):
+    """The compiled decode program's conditional and every op inside it
+    that carries metadata (the bisection's whiles and fusions) lie under
+    ``kv_page_encode``, as do the graft's encode and scatter."""
+    from repro.core.packed import KV_ENCODE_SCOPE
+    from repro.runtime.telemetry import hlo_op_scopes, in_scope
+
+    cfg, model, params = served
+    with kv_quant_scope(KVQ):
+        eng = PVQEngine(model, params, n_slots=2, max_len=24)
+        text = _decode_text(eng)
+        with kv_quant_scope(None):  # the prefill's cache is dense
+            pre = jax.eval_shape(
+                lambda: eng._prefill_fn(eng.params, jnp.zeros((1, 16), jnp.int32),
+                                        jnp.full((1,), 9, jnp.int32))[1]
+            )
+        graft = eng._graft.lower(
+            eng.cache, pre, np.zeros((1,), np.int32),
+            np.zeros((1, 2), np.int32), np.full((1,), 9, np.int32),
+        ).compile().as_text()
+    scopes = hlo_op_scopes(text)
+    comps = _hlo_computations(text)
+    insts = {name: (op, rest) for c in comps.values() for name, op, rest in c}
+    conds = [n for n, (op, _) in insts.items()
+             if op == "conditional" and in_scope(scopes.get(n, ""), KV_ENCODE_SCOPE)]
+    assert len(conds) == 1
+    todo, seen, inside = _called(insts[conds[0]][1]), set(), []
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for name, op, rest in comps[c]:
+            todo += _called(rest)
+            inside.append((name, op))
+    ops = {op for _, op in inside}
+    assert {"while", "fusion"} <= ops
+    # argument plumbing (tuples, bitcasts, constants) runs nothing and may
+    # carry its caller's metadata; XLA's own copies carry none
+    plumbing = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast"}
+    tagged = [n for n, op in inside if n in scopes and op not in plumbing]
+    assert tagged and all(in_scope(scopes[n], KV_ENCODE_SCOPE) for n in tagged)
+    graft_scopes = hlo_op_scopes(graft)
+    # the graft encodes each layer of the stack under vmap: vmap(kv_page_encode)
+    graft_ops = {op for c in _hlo_computations(graft).values() for n, op, _ in c
+                 if in_scope(graft_scopes.get(n, ""), KV_ENCODE_SCOPE)}
+    assert "while" in graft_ops and graft_ops & {"scatter", "fusion", "dynamic-update-slice"}
+
+
+def _without_metadata(text):
+    import re
+
+    lines = text.splitlines()
+    body = next(i for i, l in enumerate(lines) if l.startswith(("%", "ENTRY")))
+    return re.sub(r", metadata=\{[^}]*\}", "", "\n".join(lines[:1] + lines[body:]))
+
+
+def test_kv_page_encode_scope_changes_metadata_only(served, monkeypatch):
+    """The compiled decode program with metadata stripped is the same with
+    the scope and with the scope made a no-op."""
+    import contextlib
+
+    from repro.core import packed
+
+    cfg, model, params = served
+    with kv_quant_scope(KVQ):
+        scoped = _decode_text(PVQEngine(model, params, n_slots=2, max_len=24))
+        monkeypatch.setattr(packed, "_kv_encode_scope", contextlib.nullcontext)
+        plain = _decode_text(PVQEngine(model, params, n_slots=2, max_len=24))
+    marker = f"/{packed.KV_ENCODE_SCOPE}/"
+    assert marker in scoped and marker not in plain  # two compiles, not one
+    assert _without_metadata(scoped) == _without_metadata(plain)
+
+
+def test_kv_quality_probe_only_when_asked(served):
+    """The eager KV re-encode runs only for an engine built with
+    ``kv_probes`` (``serve --metrics-out``), never merely because the
+    registry is on."""
+    from repro.runtime import obs
+
+    cfg, model, params = served
+    prev = obs.set_enabled(True)
+    try:
+        with kv_quant_scope(KVQ):
+            for probes, want in ((0, 0), (2, 2)):
+                obs.registry().clear()
+                trace = poisson_trace(
+                    3, rate=0.0, vocab=cfg.vocab_size, prompt_lens=(9, 12),
+                    max_new=2, seed=5,
+                )
+                PVQEngine(model, params, n_slots=2, max_len=24, kv_probes=probes).run(trace)
+                assert obs.registry().histogram("quant.kv_snr_db").count == want
     finally:
         obs.set_enabled(prev)
         obs.registry().clear()

@@ -1,12 +1,16 @@
 """Observability layer tests: instrument semantics (counters, gauges,
 exact-reservoir histogram percentiles), the disabled registry's true-no-op
 contract (NOOP identity + zero allocations in the engine decode-step guard
-pattern), Chrome trace-event well-formedness, metrics-JSONL schema
-round-trip, trace-count metric parity with the ``TRACE_COUNTS`` compile
-regressions, autotune hit/miss lookup counters, and the quant-quality
-probes' eager-only (never-inside-jit) behavior."""
+pattern), spans as profiler annotations (their args in the profiler's
+trace and its Chrome-format perfetto file), the scope map of a compiled
+program, metrics-JSONL schema round-trip and the validator, trace-count
+metric parity with the ``TRACE_COUNTS`` compile regressions, autotune
+hit/miss lookup counters, and the quant-quality probes' eager-only
+(never-inside-jit) behavior."""
 
 import gc
+import glob
+import gzip
 import json
 import os
 import subprocess
@@ -20,12 +24,16 @@ import pytest
 
 from repro.runtime import obs, telemetry
 from repro.runtime.telemetry import (
+    ENGINE_REQUIRED_METRICS,
+    ENGINE_REQUIRED_SPANS,
     HISTOGRAM_FIELDS,
     METRICS_SCHEMA,
     Histogram,
     MetricsRegistry,
+    hlo_op_scopes,
+    profiler_options,
+    read_host_spans,
     snr_db,
-    validate_chrome_trace,
     validate_dir,
     validate_metrics_jsonl,
 )
@@ -116,12 +124,9 @@ def test_disabled_registry_returns_noop_singleton():
     assert reg.gauge("b") is telemetry.NOOP
     assert reg.histogram("c") is telemetry.NOOP
     assert reg.span("d") is telemetry.NOOP
-    with reg.span("d"):  # NOOP doubles as a context manager
-        pass
-    reg.trace_counter("e", 1.0)
-    reg.event("f")
+    with reg.span("d", args={"a": 1}) as sp:  # NOOP doubles as a context manager
+        sp.set_metadata(b=2)
     assert reg.snapshot() == []
-    assert reg.chrome_trace()["traceEvents"] == []
 
 
 def test_disabled_decode_step_guard_pattern_allocates_nothing():
@@ -131,14 +136,19 @@ def test_disabled_decode_step_guard_pattern_allocates_nothing():
     assert not obs.enabled()
 
     def step_hook():
-        span = obs.NOOP
-        if obs.enabled():
-            span = obs.span("engine/decode_step", args={"active": 1})
+        span = obs.span("engine/decode_step")
         with span:
-            pass
-        if obs.enabled():
-            obs.gauge("engine.queue_depth").set(0)
-            obs.counter("engine.decode_steps").inc()
+            with obs.span("engine/decode/prepare"):
+                pass
+            if obs.enabled():
+                span.set_metadata(active=1, n_slots=2)
+            with obs.span("engine/decode/launch"):
+                pass
+            with obs.span("engine/decode/wait"):
+                pass
+            with obs.span("engine/decode/commit"):
+                if obs.enabled():
+                    obs.gauge("engine.queue_depth").set(0)
 
     step_hook()  # warm any lazy import/attribute state
     gc.collect()
@@ -158,24 +168,38 @@ def test_disabled_decode_step_guard_pattern_allocates_nothing():
 # ---------------------------------------------------------------------------
 
 
-def test_chrome_trace_well_formed(tmp_path):
-    reg = MetricsRegistry(enabled=True)
-    with reg.span("engine/decode_step", args={"active": 2}):
-        pass
-    reg.trace_counter("engine.queue_depth", 3.0)
-    reg.event("engine/admit", args={"rid": 7})
-    path = str(tmp_path / "trace.json")
-    reg.export_chrome_trace(path)
+def _profile_spans(outdir, reg):
+    """Spans of an enabled registry written under ``outdir`` by the JAX
+    profiler, as ``serve --metrics-out`` runs it."""
+    with jax.profiler.trace(str(outdir), create_perfetto_trace=True,
+                            profiler_options=profiler_options()):
+        with reg.span("engine/decode_step", args={"active": 2}) as step:
+            with reg.span("engine/decode/wait"):
+                jnp.ones(8).block_until_ready()
+            step.set_metadata(pages_completed=1)
 
-    with open(path) as f:
+
+def test_chrome_trace_well_formed(tmp_path):
+    """Spans are profiler annotations: the perfetto file the profiler
+    writes is Chrome trace-event JSON holding them, args included, and
+    the ``.xplane.pb`` beside it holds them on the device ops' clock."""
+    reg = MetricsRegistry(enabled=True)
+    _profile_spans(tmp_path, reg)
+    [path] = glob.glob(str(tmp_path / "**" / "perfetto_trace.json.gz"), recursive=True)
+    with gzip.open(path, "rt") as f:
         doc = json.load(f)  # plain JSON, perfetto-loadable
-    assert doc["displayTimeUnit"] == "ms"
-    events = validate_chrome_trace(path)
-    by_ph = {e["ph"]: e for e in events}
-    assert by_ph["X"]["name"] == "engine/decode_step"
-    assert by_ph["X"]["dur"] >= 0 and by_ph["X"]["args"]["active"] == 2
-    assert by_ph["C"]["args"]["value"] == 3.0
-    assert by_ph["i"]["s"] == "p"
+    events = doc["traceEvents"] if isinstance(doc, dict) else doc
+    by_name = {e.get("name"): e for e in events}
+    step = by_name["engine/decode_step"]
+    assert step["ph"] == "X" and step["dur"] >= 0
+    assert {"active", "pages_completed"} <= set(step.get("args", {}))
+    spans = read_host_spans(str(tmp_path))
+    assert [s["name"] for s in spans] == ["engine/decode_step", "engine/decode/wait"]
+    outer, inner = spans
+    assert outer["args"] == {"active": 2, "pages_completed": 1}
+    assert outer["start"] <= inner["start"]
+    assert inner["start"] + inner["dur"] <= outer["start"] + outer["dur"]
+    assert reg.snapshot() == []  # spans are not registry instruments
 
 
 def test_metrics_jsonl_schema_round_trip(tmp_path):
@@ -191,7 +215,12 @@ def test_metrics_jsonl_schema_round_trip(tmp_path):
     hist = by_name["engine.request_latency_s"]
     assert hist["count"] == 3 and hist["exact"] is True
     assert hist["p50"] == pytest.approx(0.2)
-    assert validate_dir(str(tmp_path)) == {"metrics": 3, "trace_events": 0}
+    assert validate_dir(str(tmp_path)) == {"metrics": 3}
+
+
+def _engine_metrics(reg):
+    for name in ENGINE_REQUIRED_METRICS:
+        reg.counter(name).inc()
 
 
 def test_validators_reject_malformed(tmp_path):
@@ -200,28 +229,80 @@ def test_validators_reject_malformed(tmp_path):
                                        "name": "x", "labels": {}, "value": 1}) + "\n")
     with pytest.raises(ValueError, match="bad schema"):
         validate_metrics_jsonl(str(bad_metrics))
-    bad_trace = tmp_path / "trace.json"
-    bad_trace.write_text(json.dumps({"traceEvents": [{"name": "x", "ph": "Z", "ts": 0}]}))
-    with pytest.raises(ValueError, match="bad phase"):
-        validate_chrome_trace(str(bad_trace))
+    reg = MetricsRegistry(enabled=True)
+    _engine_metrics(reg)
+    reg.write(str(tmp_path))
+    with pytest.raises(ValueError, match="no profiler trace"):
+        validate_dir(str(tmp_path), require_engine=True)
+    _profile_spans(tmp_path, reg)  # a trace without the admission/prefill spans
+    with pytest.raises(ValueError, match="engine/admit"):
+        validate_dir(str(tmp_path), require_engine=True)
 
 
 def test_validate_cli(tmp_path):
     reg = MetricsRegistry(enabled=True)
-    reg.counter("n").inc()
-    with reg.span("s"):
-        pass
+    _engine_metrics(reg)
     reg.write(str(tmp_path))
+    with jax.profiler.trace(str(tmp_path), profiler_options=profiler_options()):
+        for name in ENGINE_REQUIRED_SPANS:
+            with reg.span(name):
+                pass
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.getcwd(), "src")
     env.setdefault("JAX_PLATFORMS", "cpu")
     proc = subprocess.run(
-        [sys.executable, "-m", "repro.runtime.telemetry", "--validate", str(tmp_path)],
+        [sys.executable, "-m", "repro.runtime.telemetry", "--validate", str(tmp_path),
+         "--require-engine"],
         capture_output=True, text=True, env=env,
     )
     assert proc.returncode == 0, proc.stderr
     out = json.loads(proc.stdout)
-    assert out["ok"] is True and out["metrics"] == 1 and out["trace_events"] == 1
+    assert out["ok"] is True
+    assert out["metrics"] == len(ENGINE_REQUIRED_METRICS)
+    assert out["engine_spans"] == len(ENGINE_REQUIRED_SPANS)
+
+
+def test_serve_metrics_out_writes_metrics_and_a_profiler_trace(tmp_path):
+    """``serve --metrics-out DIR`` on the engine path: metrics.jsonl and a
+    profiler trace (with its perfetto file) that ``--validate DIR
+    --require-engine`` accepts."""
+    out = tmp_path / "obs"
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.getcwd(), "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
+    proc = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", "--arch", "smollm-360m", "--reduced",
+         "--prompt-len", "12", "--gen", "4", "--engine", "--engine-slots", "2",
+         "--requests", "3", "--rate", "0", "--pvq", "--act-int8", "--kv-pvq",
+         "--kv-block", "8", "--kv-group", "16", "--metrics-out", str(out)],
+        capture_output=True, text=True, env=env, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert glob.glob(str(out / "**" / "perfetto_trace.json.gz"), recursive=True)
+    counts = validate_dir(str(out), require_engine=True)
+    assert counts["engine_spans"] > len(ENGINE_REQUIRED_SPANS)
+    recs = {r["name"]: r for r in validate_metrics_jsonl(str(out / "metrics.jsonl"))}
+    steps = [s for s in read_host_spans(str(out)) if s["name"] == "engine/decode_step"]
+    assert recs["engine.decode_steps"]["value"] == len(steps)
+    assert recs["engine.admissions"]["value"] == 3
+
+
+def test_hlo_op_scopes_of_a_compiled_program():
+    """A named scope reaches the compiled instructions' metadata, and the
+    map puts the conditional and the ops of its branch under it."""
+    def f(x, p):
+        with jax.named_scope("kv_page_encode"):
+            y = jax.lax.cond(p, lambda z: z + jnp.sin(z), lambda z: z, x)
+        return y * 3
+
+    x = jnp.ones((8,), jnp.float32)
+    text = jax.jit(f).lower(x, True).compile().as_text()
+    scopes = hlo_op_scopes(text)
+    conds = [n for n in scopes if n.startswith("cond")]
+    assert conds and all(scopes[n].endswith("kv_page_encode/cond") for n in conds)
+    assert any("kv_page_encode/cond/branch_1_fun/" in v for v in scopes.values())
+    assert not any("kv_page_encode" in v for n, v in scopes.items() if n.startswith("multiply"))
 
 
 # ---------------------------------------------------------------------------
