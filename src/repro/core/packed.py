@@ -566,6 +566,15 @@ class PagedKV:
         """PackedKV-compatible alias: the PVQ encode granularity."""
         return self.page
 
+    @property
+    def encode_chunk(self) -> int:
+        """Rings PVQ-encoded per trip of :meth:`append`'s encode loop: the
+        mean number of slots that complete a page per decode step when
+        positions are spread, ``ceil(n_slots / page)``.  Larger trips
+        encode rings no slot needs; each further trip adds the
+        projection's loops, several hundred device ops per layer."""
+        return -(-self.n_slots // self.page)
+
     def packed_end(self, filled) -> Array:
         return (filled // self.page) * self.page
 
@@ -670,26 +679,29 @@ class PagedKV:
         Every slot's row lands in its tail ring at ``pos % page``; slots
         whose write completes a block (``(pos + 1) % page == 0``) get the
         whole ring PVQ-encoded and scattered to their pre-assigned
-        ``write_page`` — all other slots scatter to the trash page, so the
-        encode is one masked vector op with no per-slot control flow.
+        ``write_page``.  The encode runs only when some slot completes,
+        and then over the completing rings alone: they are put first
+        (stably) and encoded, K and V together, ``encode_chunk`` rings per
+        trip of a ``while_loop`` into a buffer whose rows past the
+        completing ones scatter to the trash page.  With
+        ``encode_chunk == n_slots`` every ring is encoded in one pass and
+        scattered to ``write_page`` or the trash page.
         """
         page = self.page
         tdt = self.tail_k.dtype
         pos = jnp.asarray(pos, jnp.int32)
         slot_in_ring = jnp.mod(pos, page)
 
-        upd_row = jax.vmap(
-            lambda ring, row, p: jax.lax.dynamic_update_slice_in_dim(
-                ring, row, p, axis=0
-            )
-        )
-        tail_k = upd_row(self.tail_k, k_new.astype(tdt), slot_in_ring)
-        tail_v = upd_row(self.tail_v, v_new.astype(tdt), slot_in_ring)
+        # a select, not a per-slot scatter: TPU runs that as a loop over
+        # the slots, dozens of device ops per layer and step
+        row = (jnp.arange(page) == slot_in_ring[:, None])[:, :, None, None]
+        tail_k = jnp.where(row, k_new.astype(tdt), self.tail_k)
+        tail_v = jnp.where(row, v_new.astype(tdt), self.tail_v)
 
         completes = jnp.mod(pos + 1, page) == 0  # (n_slots,)
         dest = jnp.where(completes, self.write_page, self.trash_page)
 
-        def encode(pools):
+        def encode_all(pools):
             kpg, ksg, vpg, vsg = pools
             pk, sk = _kv_encode_planes(tail_k.astype(jnp.float32), self.group, self.k)
             pv, sv = _kv_encode_planes(tail_v.astype(jnp.float32), self.group, self.k)
@@ -698,6 +710,53 @@ class PagedKV:
                 kpg.at[dest].set(pk), ksg.at[dest].set(sk),
                 vpg.at[dest].set(pv), vsg.at[dest].set(sv),
             )
+
+        def encode_rings(rk, rv):
+            # K and V rings as one flat batch of head vectors: the
+            # projection's loops run once per trip, not once for each, and
+            # a 2-D batch compiles to fewer device ops per loop step
+            x = jnp.concatenate([rk, rv]).astype(jnp.float32)
+            p, s = _kv_encode_planes(x.reshape(-1, x.shape[-1]), self.group, self.k)
+            p, s = p.reshape(x.shape), s.reshape(x.shape[:-1] + s.shape[-1:])
+            r = rk.shape[0]
+            return p[:r], s[:r], p[r:], s[r:]
+
+        ns, c = self.n_slots, self.encode_chunk
+
+        def encode_completing(pools):
+            rows = -(-ns // c) * c  # whole trips, so no slice is clamped
+            # rank of each slot with the completing ones first, each group in
+            # slot order; order[r] is the slot of rank r (slot 0 past ns)
+            done = completes.astype(jnp.int32)
+            n = jnp.sum(done)
+            slot = jnp.arange(ns)
+            before = jnp.cumsum(done) - done
+            rank = jnp.where(completes, before, n + slot - before)
+            order = jnp.sum(
+                jnp.where(rank[None, :] == jnp.arange(rows)[:, None], slot[None, :], 0), axis=1
+            )
+
+            def trip(carry):
+                j, bufs = carry
+                idx = jax.lax.dynamic_slice_in_dim(order, j * c, c)
+                out = encode_rings(tail_k[idx], tail_v[idx])
+                bufs = tuple(
+                    jax.lax.dynamic_update_slice_in_dim(b, x, j * c, axis=0)
+                    for b, x in zip(bufs, out)
+                )
+                return j + 1, bufs
+
+            # rows the trips leave unwritten go to the trash page, so the
+            # fill is never read; filled from n, not a literal, so XLA keeps
+            # the fill under the encode's scope
+            bufs = tuple(jnp.full((rows,) + p.shape[1:], n, p.dtype) for p in pools)
+            _, bufs = jax.lax.while_loop(
+                lambda carry: carry[0] * c < n, trip, (jnp.int32(0), bufs)
+            )
+            dest_sorted = jnp.where(jnp.arange(rows) < n, dest[order], self.trash_page)
+            return tuple(pool.at[dest_sorted].set(b) for pool, b in zip(pools, bufs))
+
+        encode = encode_all if c == ns else encode_completing
 
         pools = (self.k_pages, self.k_page_scales, self.v_pages, self.v_page_scales)
         any_completes = jnp.any(completes)

@@ -73,6 +73,7 @@ STAT_METRICS = {
     "admissions": "engine.admissions",
     "evictions": "engine.evictions",
     "kv_pages_completed": "engine.kv_pages_completed",
+    "kv_encode_chunks": "engine.kv_encode_chunks",
     "prefix_hits": "prefix_cache.hit",
     "prefix_misses": "prefix_cache.miss",
     "prefix_pages_shared": "prefix_cache.pages_shared",
@@ -442,6 +443,10 @@ class PVQEngine:
         self.prefix_cache = bool(prefix_cache) and self.prefill_chunk is not None
         self.alloc = PageAllocator(self.n_pages)
         self.cache = model.init_paged_cache(self.n_slots, self.n_pages, self.max_pages)
+        # rings the decode step's page encode takes per trip (the same for
+        # every layer: it is a function of n_slots and page)
+        paged = [c for c in jax.tree.leaves(self.cache, is_leaf=is_paged_kv) if is_paged_kv(c)]
+        self.encode_chunk = paged[0].encode_chunk if paged else self.n_slots
         self.slots: List[Optional[_Slot]] = [None] * self.n_slots
         self._page_table = np.full(
             (self.n_slots, self.max_pages), self.alloc.trash, np.int32
@@ -456,7 +461,7 @@ class PVQEngine:
         # registry under the names in STAT_METRICS
         self.stats: Dict[str, int] = {
             "steps": 0, "active_slot_steps": 0, "evictions": 0, "decode_tokens": 0,
-            "admissions": 0, "kv_pages_completed": 0,
+            "admissions": 0, "kv_pages_completed": 0, "kv_encode_chunks": 0,
             "prefill_batches": 0, "prefill_rows": 0, "chunks": 0,
             "prefix_hits": 0, "prefix_misses": 0, "prefix_pages_shared": 0,
         }
@@ -952,8 +957,10 @@ class PVQEngine:
         reserved at admission, so they always make progress.
 
         With the registry on, the step is one ``engine/decode_step`` span
-        (args ``active``, ``n_slots``, ``queue``, ``free_pages`` and
-        ``pages_completed``) holding four that follow one another:
+        (args ``active``, ``n_slots``, ``queue``, ``free_pages``,
+        ``pages_completed`` and ``encode_chunks``, the trips of the page
+        encode's loop: ``ceil(pages_completed / encode_chunk)``) holding
+        four that follow one another:
         ``engine/decode/prepare`` (eviction, inputs, page allocation),
         ``engine/decode/launch`` (the call of the compiled step),
         ``engine/decode/wait`` (``device_get`` of the tokens) and
@@ -980,12 +987,14 @@ class PVQEngine:
                         self._page_table[s, st.length // self.page] = pid
                         write_page[s] = pid
                         completed += 1
+                chunks = -(-completed // self.encode_chunk)
                 self.stats["kv_pages_completed"] += completed
+                self.stats["kv_encode_chunks"] += chunks
             if obs.enabled():
                 span.set_metadata(
                     active=len(active), n_slots=self.n_slots,
                     queue=len(self.pending), free_pages=self.alloc.available,
-                    pages_completed=completed,
+                    pages_completed=completed, encode_chunks=chunks,
                 )
             with obs.span("engine/decode/launch"):
                 tok_ids, self.cache = self._decode(

@@ -5,6 +5,9 @@ blocks), cross-sequence isolation, per-sequence EOS/max_tokens stopping,
 the compile-count regressions for both the engine decode step and the
 bucketed ``serve.generate`` loop, and the slot-pool cache sharding rules."""
 
+import copy
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -145,6 +148,77 @@ def test_paged_append_matches_packed_append():
     np.testing.assert_array_equal(
         np.asarray(got.tail_k[0, :t]), np.asarray(ref.tail_k[0, :t])
     )
+
+
+def _staggered_append(n_complete, seed=0):
+    """A 9-slot pool (page 8, so ``encode_chunk`` 2) with random tails and
+    pools, one decode step's rows and positions in which ``n_complete``
+    randomly chosen slots complete a page, each to an out-of-order
+    physical page.  Returns ``(paged, k_new, v_new, pos, dest)``, ``dest``
+    the completing slots' pages."""
+    ns, n_kv, hd, page = 9, 2, 16, KVQ.block
+    rng = np.random.default_rng(seed)
+    paged = PagedKV.init(ns, 24, 3, n_kv, hd, kvq=KVQ, dtype=jnp.float32)
+    paged = dataclasses.replace(
+        paged,
+        k_pages=jnp.asarray(rng.integers(-127, 128, paged.k_pages.shape), jnp.int8),
+        v_pages=jnp.asarray(rng.integers(-127, 128, paged.v_pages.shape), jnp.int8),
+        k_page_scales=jnp.asarray(rng.random(paged.k_page_scales.shape), jnp.float32),
+        v_page_scales=jnp.asarray(rng.random(paged.v_page_scales.shape), jnp.float32),
+        tail_k=jnp.asarray(rng.standard_normal(paged.tail_k.shape), jnp.float32),
+        tail_v=jnp.asarray(rng.standard_normal(paged.tail_v.shape), jnp.float32),
+    )
+    done = rng.permutation(ns)[:n_complete]
+    ring = np.where(np.isin(np.arange(ns), done), page - 1, rng.integers(0, page - 1, ns))
+    pos = (rng.integers(0, 3, ns) * page + ring).astype(np.int32)
+    wp = np.full((ns,), paged.trash_page, np.int32)
+    dest = {int(s): int(p) for s, p in zip(done, rng.permutation(24)[:n_complete])}
+    for s, p in dest.items():
+        wp[s] = p
+    pt = np.full((ns, 3), paged.trash_page, np.int32)
+    paged = paged.with_tables(jnp.asarray(pt), jnp.asarray(wp))
+    k_new = jnp.asarray(rng.standard_normal((ns, 1, n_kv, hd)), jnp.float32)
+    v_new = jnp.asarray(rng.standard_normal((ns, 1, n_kv, hd)), jnp.float32)
+    return paged, k_new, v_new, jnp.asarray(pos), dest
+
+
+@pytest.mark.parametrize("n_complete", [0, 1, 2, 3, 9])
+def test_paged_append_encodes_only_completing_slots(n_complete, monkeypatch):
+    """With ``encode_chunk`` < ``n_slots`` the append encodes the completing
+    slots' rings in trips of 2: each destination page holds
+    ``_kv_encode_planes`` of that slot's ring (the old tail with this
+    step's row written), bit for bit and as the one-pass encode of every
+    ring writes it; every other page and the tails of all slots are as
+    before the step, but for the row each slot writes."""
+    from repro.core.packed import _kv_encode_planes
+
+    paged, k_new, v_new, pos, dest = _staggered_append(n_complete)
+    assert (paged.n_slots, paged.page, paged.encode_chunk) == (9, 8, 2)
+    append = jax.jit(lambda p, k, v, q: p.append(k, v, q))
+    got = append(paged, k_new, v_new, pos)
+    # the one-pass body (encode_chunk == n_slots): every ring at once
+    monkeypatch.setattr(PagedKV, "encode_chunk", property(lambda self: self.n_slots))
+    one_pass = jax.jit(lambda p, k, v, q: p.append(k, v, q))(paged, k_new, v_new, pos)
+
+    ring_slot = np.asarray(pos) % paged.page
+    for name, new in (("tail_k", k_new), ("tail_v", v_new)):
+        want = np.array(getattr(paged, name))
+        want[np.arange(9), ring_slot] = np.asarray(new)[:, 0]
+        np.testing.assert_array_equal(np.asarray(getattr(got, name)), want)
+    # compiled, as in the decode step (op by op rounds the scales otherwise)
+    encode = jax.jit(_kv_encode_planes, static_argnums=(1, 2))
+    planes = {"k": encode(got.tail_k, paged.group, paged.k),
+              "v": encode(got.tail_v, paged.group, paged.k)}
+    untouched = np.setdiff1d(np.arange(paged.n_pages), list(dest.values()))
+    for kv in ("k", "v"):
+        for pool, want in ((f"{kv}_pages", planes[kv][0]), (f"{kv}_page_scales", planes[kv][1])):
+            g, ref = np.asarray(getattr(got, pool)), np.asarray(getattr(one_pass, pool))
+            for s, p in dest.items():
+                np.testing.assert_array_equal(g[p], np.asarray(want)[s], err_msg=pool)
+            np.testing.assert_array_equal(g[:-1], ref[:-1], err_msg=pool)
+            np.testing.assert_array_equal(
+                g[untouched], np.asarray(getattr(paged, pool))[untouched], err_msg=pool
+            )
 
 
 # ---------------------------------------------------------------------------
@@ -418,7 +492,8 @@ def test_engine_decode_spans_nest_and_carry_args(traced_run):
             assert a["start"] + a["dur"] <= b["start"]
         assert inside[-1]["start"] + inside[-1]["dur"] <= end
         args = st["args"]
-        assert set(args) == {"active", "n_slots", "queue", "free_pages", "pages_completed"}
+        assert set(args) == {"active", "n_slots", "queue", "free_pages",
+                             "pages_completed", "encode_chunks"}
         assert 1 <= args["active"] <= args["n_slots"] == eng.n_slots
     assert sum(s["args"]["active"] for s in steps) == eng.stats["decode_tokens"]
     assert sum(s["args"]["pages_completed"] for s in steps) == eng.stats["kv_pages_completed"]
@@ -437,6 +512,43 @@ def test_kv_pages_completed_counts_pages_decode_steps_complete(traced_run):
         if (p + 1) % page == 0
     )
     assert want > 0 and eng.stats["kv_pages_completed"] == want
+
+
+def test_kv_encode_chunks_counts_trips_of_the_encode(traced_run):
+    """``stats["kv_encode_chunks"]`` is the sum over decode steps of
+    ``ceil(pages_completed / encode_chunk)``, and each step's span carries
+    its own term as ``encode_chunks``."""
+    spans, eng = traced_run["spans"], traced_run["eng"]
+    steps = [s["args"] for s in spans if s["name"] == "engine/decode_step"]
+    assert steps and eng.encode_chunk == 1
+    for args in steps:
+        assert args["encode_chunks"] == -(-args["pages_completed"] // eng.encode_chunk)
+    assert eng.stats["kv_encode_chunks"] == sum(a["encode_chunks"] for a in steps) > 0
+
+
+def test_engine_chunked_encode_serves_the_one_pass_tokens(served, monkeypatch):
+    """Nine slots admitted in one wave complete their pages on the same
+    steps, so the page encode takes five trips of two rings there: the
+    tokens are those of the one-pass encode of every ring, and the count
+    of trips is higher by that."""
+    cfg, model, params = served
+    with kv_quant_scope(KVQ):
+        trace = poisson_trace(
+            9, rate=0.0, vocab=cfg.vocab_size, prompt_lens=(10, 10),
+            max_new=12, seed=23,
+        )
+
+        def serve():
+            eng = PVQEngine(model, params, n_slots=9, max_len=32, prefill_batch=9)
+            return eng, eng.run(copy.deepcopy(trace))
+
+        eng, res = serve()
+        monkeypatch.setattr(PagedKV, "encode_chunk", property(lambda self: self.n_slots))
+        one_eng, one_res = serve()
+    assert (eng.encode_chunk, one_eng.encode_chunk) == (2, 9)
+    assert res["outputs"] == one_res["outputs"]
+    assert eng.stats["kv_pages_completed"] == one_eng.stats["kv_pages_completed"] > 0
+    assert eng.stats["kv_encode_chunks"] > one_eng.stats["kv_encode_chunks"]
 
 
 def _hlo_computations(text):
@@ -473,17 +585,69 @@ def _decode_text(eng):
     ).compile().as_text()
 
 
+def _scoped_encode(text):
+    """The compiled program's conditionals under ``kv_page_encode``, and
+    ``(instruction, opcode, fused)`` of every op their branches run, the
+    ops inside fusions marked ``fused``."""
+    import re
+
+    from repro.core.packed import KV_ENCODE_SCOPE
+    from repro.runtime.telemetry import hlo_op_scopes, in_scope
+
+    scopes = hlo_op_scopes(text)
+    comps = _hlo_computations(text)
+    insts = {name: (op, rest) for c in comps.values() for name, op, rest in c}
+    conds = [n for n, (op, _) in insts.items()
+             if op == "conditional" and in_scope(scopes.get(n, ""), KV_ENCODE_SCOPE)]
+    todo = [(c, False) for n in conds for c in _called(insts[n][1])]
+    seen, inside = set(), []
+    while todo:
+        c, fused = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for name, op, rest in comps[c]:
+            todo += [(x, fused) for x in _called(rest)]
+            todo += [(x, True) for x in re.findall(r"calls=%([\w.-]+)", rest)]
+            inside.append((name, op, fused))
+    return conds, inside, scopes
+
+
 def test_kv_page_encode_scope_covers_the_encode(served):
-    """The compiled decode program's conditional and every op inside it
+    """The compiled decode program's one conditional and every op inside it
     that carries metadata (the bisection's whiles and fusions) lie under
-    ``kv_page_encode``, as do the graft's encode and scatter."""
+    ``kv_page_encode``, as do the graft's encode and scatter.  At 1 slot
+    its ring is encoded in one pass; at 9 slots (``encode_chunk`` 2) the
+    loop over the completing rings and its ring gathers lie inside the
+    same conditional, which stays the only one."""
     from repro.core.packed import KV_ENCODE_SCOPE
     from repro.runtime.telemetry import hlo_op_scopes, in_scope
 
     cfg, model, params = served
+    # argument plumbing (tuples, bitcasts, constants) runs nothing and may
+    # carry its caller's metadata; XLA's own copies carry none
+    plumbing = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast"}
+    for n_slots, chunk in ((1, 1), (9, 2)):
+        with kv_quant_scope(KVQ):
+            eng = PVQEngine(model, params, n_slots=n_slots, max_len=24)
+            conds, inside, scopes = _scoped_encode(_decode_text(eng))
+        assert eng.encode_chunk == chunk and len(conds) == 1
+        top = [(n, op) for n, op, fused in inside if not fused]
+        assert {"while", "fusion"} <= {op for _, op in top}
+        tagged = [n for n, op in top if n in scopes and op not in plumbing]
+        assert tagged and all(in_scope(scopes[n], KV_ENCODE_SCOPE) for n in tagged)
+        # the encode's own ops, outside the PVQ projection's loops
+        own = [(n, op) for n, op, _ in inside
+               if n in scopes and "pvq_quantize_direction_fast" not in scopes[n]]
+        loops = [n for n, op in own if op == "while"]
+        gathers = [n for n, op in own if op == "gather"]
+        assert len(loops) == (chunk < n_slots)
+        if chunk < n_slots:
+            assert len(gathers) >= 2  # tail_k[idx], tail_v[idx] in each trip
+            assert all(in_scope(scopes[n], KV_ENCODE_SCOPE) for n in loops + gathers)
+
     with kv_quant_scope(KVQ):
         eng = PVQEngine(model, params, n_slots=2, max_len=24)
-        text = _decode_text(eng)
         with kv_quant_scope(None):  # the prefill's cache is dense
             pre = jax.eval_shape(
                 lambda: eng._prefill_fn(eng.params, jnp.zeros((1, 16), jnp.int32),
@@ -493,28 +657,6 @@ def test_kv_page_encode_scope_covers_the_encode(served):
             eng.cache, pre, np.zeros((1,), np.int32),
             np.zeros((1, 2), np.int32), np.full((1,), 9, np.int32),
         ).compile().as_text()
-    scopes = hlo_op_scopes(text)
-    comps = _hlo_computations(text)
-    insts = {name: (op, rest) for c in comps.values() for name, op, rest in c}
-    conds = [n for n, (op, _) in insts.items()
-             if op == "conditional" and in_scope(scopes.get(n, ""), KV_ENCODE_SCOPE)]
-    assert len(conds) == 1
-    todo, seen, inside = _called(insts[conds[0]][1]), set(), []
-    while todo:
-        c = todo.pop()
-        if c in seen:
-            continue
-        seen.add(c)
-        for name, op, rest in comps[c]:
-            todo += _called(rest)
-            inside.append((name, op))
-    ops = {op for _, op in inside}
-    assert {"while", "fusion"} <= ops
-    # argument plumbing (tuples, bitcasts, constants) runs nothing and may
-    # carry its caller's metadata; XLA's own copies carry none
-    plumbing = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast"}
-    tagged = [n for n, op in inside if n in scopes and op not in plumbing]
-    assert tagged and all(in_scope(scopes[n], KV_ENCODE_SCOPE) for n in tagged)
     graft_scopes = hlo_op_scopes(graft)
     # the graft encodes each layer of the stack under vmap: vmap(kv_page_encode)
     graft_ops = {op for c in _hlo_computations(graft).values() for n, op, _ in c

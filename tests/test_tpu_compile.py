@@ -206,7 +206,11 @@ def test_encoder_compiles(one_chip, g, n, k_pulses):
 
 def test_engine_decode_step_lowers_to_mosaic(one_chip, monkeypatch):
     """The engine's whole jitted decode step (reduced smollm, full quantized
-    stack) compiles for the chip with its kernels as Mosaic calls."""
+    stack) compiles for the chip with its kernels as Mosaic calls and one
+    conditional, the KV page encode: at 1 slot the one-pass encode of its
+    ring, at 9 (``encode_chunk`` 2) the loop over completing rings."""
+    import re
+
     from repro.configs import get_config
     from repro.core.packed import quantize_params
     from repro.core.quantize import (
@@ -227,14 +231,17 @@ def test_engine_decode_step_lowers_to_mosaic(one_chip, monkeypatch):
         lambda key: quantize_params(model.init(key, max_seq=64), policy),
         jax.random.PRNGKey(0),
     )
-    with act_quant_scope(ActQuant()), kv_quant_scope(KVQuant(block=8, group=16)):
-        eng = PVQEngine(model, params, n_slots=2, max_len=64, prefill_chunk=2)
-        spec = lambda t: jax.tree.map(  # noqa: E731
-            lambda a: _spec(one_chip, a.shape, a.dtype), t
-        )
-        i32 = lambda *shape: _spec(one_chip, shape, jnp.int32)  # noqa: E731
-        hlo = _compile(
-            eng._decode_fn, spec(params), spec(eng.cache), i32(2, 1), i32(2),
-            i32(2, eng.max_pages), i32(2),
-        )
-    assert "tpu_custom_call" in hlo
+    spec = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: _spec(one_chip, a.shape, a.dtype), t
+    )
+    i32 = lambda *shape: _spec(one_chip, shape, jnp.int32)  # noqa: E731
+    for n_slots, chunk in ((1, 1), (9, 2)):
+        with act_quant_scope(ActQuant()), kv_quant_scope(KVQuant(block=8, group=16)):
+            eng = PVQEngine(model, params, n_slots=n_slots, max_len=64, prefill_chunk=2)
+            hlo = _compile(
+                eng._decode_fn, spec(params), spec(eng.cache), i32(n_slots, 1),
+                i32(n_slots), i32(n_slots, eng.max_pages), i32(n_slots),
+            )
+        assert eng.encode_chunk == chunk
+        assert "tpu_custom_call" in hlo
+        assert len(re.findall(r" conditional\(", hlo)) == 1, n_slots
