@@ -45,7 +45,7 @@ def main(argv) -> int:
         if c["name"] not in names:
             continue
         config = spec_lib._json(spec_lib.ROOT / c["file"])
-        arch, e = weights.arch_of(config), config["engine"]
+        e = config["engine"]
 
         def report(what, compiled):
             m = compiled.memory_analysis()

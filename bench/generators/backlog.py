@@ -8,7 +8,11 @@ Parameters (``bench/traffic/<mix>.json``):
 
 Requests come in rounds of ``n_slots``.  Every round holds the same
 (prompt, output) pairs, stratified quantiles of the two lengths joined by
-a fixed pairing; the seed orders each round and draws the token ids.  Set-up
+a fixed pairing.  The seed orders the first round and draws the token
+ids.  Later rounds come in one order for every seed: a request admitted
+while others decode costs the encode of its prompt's pages, so the size
+of the one that takes a freed slot is work, and every seed then admits
+the same sizes at the same steps.  Set-up
 queues the first round and ``queue_factor`` more and admits until every
 slot decodes, so the first round fills the slots whatever the seed; then
 the window opens.  A request is due the moment it joins the queue.
@@ -43,7 +47,9 @@ class Generator:
 
     def _next(self) -> Dict:
         if not self._queue:
-            order = draw.rng_for(self.ctx["seed"], 2, self._rounds).permutation(self.round)
+            # the seed orders the first round only (see the docstring)
+            seed = self.ctx["seed"] if self._rounds == 0 else 0
+            order = draw.rng_for(seed, 2, self._rounds).permutation(self.round)
             self._rounds += 1
             for i in order:
                 plen, out = self.pairs[i]

@@ -16,6 +16,7 @@ import sys
 import tempfile
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
@@ -53,7 +54,7 @@ class Step:
 class Run:
     """What the metric readers read."""
 
-    arch: Dict
+    arch: Dict  # ref.arch of the configuration
     config: Dict
     records: List[Rec]
     t0: float  # window open (host clock)
@@ -62,6 +63,7 @@ class Run:
     steps: List[Step]
     trace: Optional[Dict] = None  # harness.trace.reduce of the window
     peaks: Optional[Dict] = None
+    ref: Optional[ModuleType] = None  # the configuration's reference module
 
     @property
     def window_s(self) -> float:
@@ -174,12 +176,12 @@ def run(spec: Dict, seed: int, seconds: float, trace: bool, *, t_start: float,
 
     log(f"compile cache: {enable_compile_cache()}")
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    config, traffic, limits = spec["config"], spec["traffic"], spec["limits"]
-    arch = weights.arch_of(config)
+    config, traffic, limits, ref = spec["config"], spec["traffic"], spec["limits"], spec["reference"]
+    arch = ref.arch(weights.model_config(config))
     e = config["engine"]
 
     t_w = time.perf_counter()
-    params, raw = weights.make(config, seed)
+    params, raw = weights.make(arch, config, seed, ref)
     jax.block_until_ready(params)
     log(f"weights made in {time.perf_counter() - t_w:.3f} s")
     set_default_act_quant(ActQuant(mode="per_row"))
@@ -243,7 +245,7 @@ def run(spec: Dict, seed: int, seconds: float, trace: bool, *, t_start: float,
         log(f"generator lateness p50 {late[len(late) // 2]:.6f} s, max {late[-1]:.6f} s over {len(late)}")
 
     run_ = Run(arch=arch, config=config, records=loop.records,
-               t0=t0, t1=t1, setup_s=setup_s, steps=loop.steps, trace=reduced)
+               t0=t0, t1=t1, setup_s=setup_s, steps=loop.steps, trace=reduced, ref=ref)
     samples = [
         {"prompt": np.asarray(r.req.prompt), "served": np.asarray(r.req.generated)}
         for r in judge.pick_samples(loop.records, int(limits["sample_tokens"]), seed)
@@ -255,7 +257,7 @@ def run(spec: Dict, seed: int, seconds: float, trace: bool, *, t_start: float,
     if tmp is not None:
         tmp.cleanup()
     t_ref = time.perf_counter()
-    reads = judge.readings(arch, raw, samples, int(e["max_len"]), control=control)
+    reads = judge.readings(ref, arch, raw, samples, int(e["max_len"]), control=control)
     checks = judge.checks(reads, limits)
     log(f"reference over {len(samples)} requests, {sum(r['served'] for r in reads)} tokens,"
         f" in {time.perf_counter() - t_ref:.1f} s")

@@ -1,10 +1,11 @@
 """The comparison that decides ``correct``.
 
 Each sampled request's prompt and served tokens run once through the
-reference (``harness.reference``), teacher-forced.  At every position that
-produced a served token the reading is the gap by which the served token's
-logit lies below the reference's best, in units of the reference logits'
-standard deviation at that position; a request reads its widest gap.  A
+reference (the configuration's module in ``bench/references``),
+teacher-forced.  At every position that produced a served token the
+reading is the gap by which the served token's logit lies below the
+reference's best, in units of the reference logits' standard deviation at
+that position; a request reads its widest gap.  A
 greedy server that computes what the configuration states serves the
 reference's best token or a near-tie of it, so its widest gap stays small;
 a fault moves tokens off the reference's best by whole logit spreads.
@@ -22,8 +23,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness.reference import make_forward
-
 
 def widest_gap(ref_rows: jax.Array, tokens: jax.Array) -> float:
     """The widest gap of ``tokens`` below the best of ``ref_rows``, in
@@ -38,20 +37,21 @@ def widest_gap(ref_rows: jax.Array, tokens: jax.Array) -> float:
 
 
 def readings(
-    arch: Dict, raw: Dict, samples: Sequence[Dict], t_pad: int, *, control: bool = False,
+    ref, arch: Dict, raw: Dict, samples: Sequence[Dict], t_pad: int, *, control: bool = False,
 ) -> List[Dict]:
-    """Per sample ``{"served", "gap"[, "control_gap"]}``.  Each sample is
-    ``{"prompt": [...], "served": [...]}`` with ``len(prompt) +
-    len(served) <= t_pad``."""
-    ref = make_forward(arch, 8)
-    low = make_forward(arch, 4) if control else None
+    """Per sample ``{"served", "gap"[, "control_gap"]}``, through the
+    reference module ``ref`` (``arch``, ``raw``: its ``arch`` and ``view``).
+    Each sample is ``{"prompt": [...], "served": [...]}`` with
+    ``len(prompt) + len(served) <= t_pad``."""
+    forward = ref.make_forward(arch, 8)
+    low = ref.make_forward(arch, 4) if control else None
     out = []
     for s in samples:
         plen, n = len(s["prompt"]), len(s["served"])
         seq = np.zeros((t_pad,), np.int32)
         seq[: plen + n] = np.concatenate([s["prompt"], s["served"]]).astype(np.int32)
         rows = slice(plen - 1, plen - 1 + n)
-        ref_rows = ref(raw, jnp.asarray(seq))[rows]
+        ref_rows = forward(raw, jnp.asarray(seq))[rows]
         rec = {"served": n, "gap": widest_gap(ref_rows, jnp.asarray(s["served"], jnp.int32))}
         if low is not None:
             rec["control_gap"] = widest_gap(ref_rows, jnp.argmax(low(raw, jnp.asarray(seq))[rows], axis=-1))

@@ -1,6 +1,7 @@
 """Find a cell's pieces by name: its entry in ``BENCHMARK.json``, the
-configuration, traffic and limit files, the generator and the metric
-readers.  Nothing here knows a cell, configuration or metric by name.
+configuration, traffic and limit files, the configuration's reference
+module, the generator and the metric readers.  Nothing here knows a cell,
+configuration, architecture or metric by name.
 """
 
 from __future__ import annotations
@@ -13,6 +14,7 @@ from typing import Dict, List, Tuple
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
+REFERENCES = BENCH / "references"
 
 
 def _json(path: Path) -> Dict:
@@ -41,17 +43,37 @@ def cell(name: str) -> Dict:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json; have {sorted(cells)}")
     w = cells[name]
     conf = {c["name"]: c for c in b["configs"]}[w["config"]]
+    config = _json(ROOT / conf["file"])
     traffic = _json(BENCH / "traffic" / f"{w['traffic']}.json")
+    ref = reference(config, conf["file"])
+    per_layer = metrics_for(b["per_layer"], name, b["end_to_end"])
+    for m, reader in per_layer:
+        for count in getattr(reader, "NEEDS", ()):
+            if not hasattr(ref, count):
+                raise AttributeError(f"metric {m['name']} of cell {name} needs {count!r}, "
+                                     f"which reference module {ref.__file__} does not define")
     return {
         "cell": w,
-        "config": _json(ROOT / conf["file"]),
+        "config": config,
+        "reference": ref,
         "traffic": traffic,
         "limits": _json(BENCH / "limits" / f"{name}.json"),
         "generator": generator(traffic["generator"]),
         "end_to_end": metrics_for(b["end_to_end"], name, None),
-        "per_layer": metrics_for(b["per_layer"], name, b["end_to_end"]),
+        "per_layer": per_layer,
         "chips": int(w["chips"]),
     }
+
+
+def reference(config: Dict, where: str = "the configuration") -> ModuleType:
+    """The reference module the configuration names under ``reference``:
+    ``bench/references/<name>.py`` (its interface: ``references/llama.py``)."""
+    if "reference" not in config:
+        raise KeyError(f"{where} names no reference module (key 'reference', a file in {REFERENCES})")
+    path = REFERENCES / f"{config['reference']}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"{where} names reference {config['reference']!r}: no file {path}")
+    return _module(path)
 
 
 def generator(kind: str) -> ModuleType:

@@ -15,8 +15,8 @@ leaf of that tree from the seed (:func:`maker`):
   embedding); weights past the logical shape are zero;
 * any other floating leaf (the RMS norm scales) ``1 + 0.1 N(0, 1)``.
 
-The reference reads the same arrays, by role, through
-:func:`reference_view`.
+The reference reads the same arrays through its module's ``view``
+(``bench/references``).
 """
 
 from __future__ import annotations
@@ -30,44 +30,26 @@ import jax.numpy as jnp
 
 from harness import draw
 
-#: reference role -> the program's parameter path, as a suffix (matmul
-#: roles and norms under the one scanned block: leading axis = layers)
-LAYER_ROLES = {
-    "wq": "mixer/wq/kernel", "wk": "mixer/wk/kernel", "wv": "mixer/wv/kernel",
-    "wo": "mixer/wo/kernel", "wi_gate": "ffn/wi_gate/kernel", "wi_up": "ffn/wi_up/kernel",
-    "wo_ffn": "ffn/wo/kernel", "ln_mix": "ln_mix/rms_scale", "ln_ffn": "ln_ffn/rms_scale",
-}
-EMBED_ROLE = "embed/embedding"
-FINAL_NORM_ROLE = "final_norm/rms_scale"
-#: (role, contraction dim key, output dim key) of the layer matmuls
-MATRICES = (("wq", "d_model", "q_dim"), ("wk", "d_model", "kv_dim"), ("wv", "d_model", "kv_dim"),
-            ("wo", "q_dim", "d_model"), ("wi_gate", "d_model", "d_ff"), ("wi_up", "d_model", "d_ff"),
-            ("wo_ffn", "d_ff", "d_model"))
 EMBED_STD = 0.02
 
 
 def model_config(config: Dict):
+    """The program's configuration of ``config["arch"]`` with the keys of
+    ``config["model"]`` replaced; a nested dict replaces fields of the
+    base's own group (``"moe": {"n_experts": 8}`` keeps every other field
+    of its ``MoEConfig``)."""
     from repro.configs import get_config
 
-    return dataclasses.replace(get_config(config["arch"]), **config["model"])
-
-
-def arch_of(config: Dict) -> Dict:
-    cfg = model_config(config)
-    return {
-        "n_layers": cfg.n_layers, "d_model": cfg.d_model, "n_heads": cfg.n_heads,
-        "n_kv_heads": cfg.n_kv_heads, "head_dim": cfg.resolved_head_dim,
-        "d_ff": cfg.d_ff, "vocab_size": cfg.vocab_size,
-        "rope_theta": cfg.rope_theta, "param_dtype": cfg.param_dtype,
-    }
-
-
-def dims(arch: Dict) -> Dict[str, int]:
-    hd = arch["head_dim"]
-    return {
-        "d_model": arch["d_model"], "d_ff": arch["d_ff"],
-        "q_dim": arch["n_heads"] * hd, "kv_dim": arch["n_kv_heads"] * hd,
-    }
+    base = get_config(config["arch"])
+    changes = {}
+    for k, v in config["model"].items():
+        if isinstance(v, dict):
+            group = getattr(base, k)
+            if not hasattr(group, "_replace"):
+                raise TypeError(f"model.{k}: {config['arch']} has no group of fields there ({group!r})")
+            v = group._replace(**v)
+        changes[k] = v
+    return dataclasses.replace(base, **changes)
 
 
 def program_tree(config: Dict):
@@ -170,41 +152,9 @@ def key(seed: int) -> jax.Array:
     return jax.random.PRNGKey(int(draw.rng_for(seed, 0).integers(0, 2**31 - 1)))
 
 
-def make(config: Dict, seed: int):
+def make(arch: Dict, config: Dict, seed: int, ref):
     """``(params, raw)``: the program's parameters made from the seed, and
-    the reference's view of the same arrays."""
+    the view of the same arrays that the reference module ``ref`` reads
+    (``arch``: its ``ref.arch`` of the configuration)."""
     params = maker(program_tree(config))(key(seed))
-    return params, reference_view(arch_of(config), params)
-
-
-def reference_view(arch: Dict, params) -> Dict:
-    """The arrays of ``params`` by role, as plain dicts:
-
-    * ``embed``: ``pulses (vocab * d / g, g) int8``, ``scales (vocab * d / g,)``;
-    * ``layers[role]`` for ``wq wk wv wo wi_gate wi_up wo_ffn``:
-      ``pulses (L, k_pad, n) int8``, ``scales (L, k_pad / g, n) f32``;
-    * ``norms``: ``ln_mix`` and ``ln_ffn`` ``(L, d)``, ``final`` ``(d,)``.
-    """
-    from repro.core.packed import is_packed
-
-    found = {}
-    for path, leaf in jax.tree_util.tree_flatten_with_path(params, is_leaf=is_packed)[0]:
-        pstr = "/".join(str(getattr(p, "key", p)) for p in path)
-        for role, suffix in list(LAYER_ROLES.items()) + [("embed", EMBED_ROLE), ("final", FINAL_NORM_ROLE)]:
-            if pstr == suffix or pstr.endswith("/" + suffix):
-                if role in found:
-                    raise ValueError(f"two leaves for {role!r}: the reference reads one scanned block")
-                found[role] = leaf
-    missing = (set(LAYER_ROLES) | {"embed", "final"}) - set(found)
-    if missing:
-        raise ValueError(f"no leaf for {sorted(missing)} in the program's tree")
-    code = lambda p: {"pulses": p.pulses, "scales": p.scales}  # noqa: E731
-    layers = {role: code(found[role]) for role, _, _ in MATRICES}
-    for role, leaf in list(layers.items()):
-        if leaf["pulses"].shape[0] != arch["n_layers"]:
-            raise ValueError(f"{role}: leading axis {leaf['pulses'].shape[0]} is not the layers")
-    return {
-        "embed": code(found["embed"]),
-        "layers": layers,
-        "norms": {"ln_mix": found["ln_mix"], "ln_ffn": found["ln_ffn"], "final": found["final"]},
-    }
+    return params, ref.view(arch, params)

@@ -1,14 +1,16 @@
 """decode_mfu: model operations of the decode tokens served in the traced
-window (``harness.work.decode_token_ops`` at each token's context) over
-the window's length times the chip's int8 peak, in %.  Every product on
-the path is int8 x int8, hence the int8 peak."""
+window (the reference module's ``decode_token_ops`` at each token's
+context) over the window's length times the chip's int8 peak, in %.
+Every product on the path is int8 x int8, hence the int8 peak."""
 
-from harness import work
+#: the reference module's count this reads; ``spec.cell`` checks it is there
+NEEDS = ("decode_token_ops",)
 
 
 def read(run):
+    count = getattr(run.ref, "decode_token_ops", None)
     steps = run.steps
-    if run.trace is None or not steps or run.peaks is None:
+    if count is None or run.trace is None or not steps or run.peaks is None:
         return None
-    ops = sum(work.decode_token_ops(run.arch, n) for s in steps for n in s.lengths)
+    ops = sum(count(run.arch, n) for s in steps for n in s.lengths)
     return 100.0 * ops / (run.trace["window_ns"] / 1e9 * run.peaks["int8_ops_per_s"])

@@ -31,6 +31,9 @@ def test_backlog_rounds_hold_the_same_sizes_in_a_seeded_order():
     sizes = lambda items: sorted((len(i["prompt"]), i["max_new"]) for i in items)  # noqa: E731
     assert sizes(ia[:8]) == sizes(ib[:8]) == sizes(ia[8:16])
     assert [len(i["prompt"]) for i in ia[:8]] != [len(i["prompt"]) for i in ib[:8]]
+    # the requests that take the places of finished ones: one order for every seed
+    later = lambda items: [(len(i["prompt"]), i["max_new"]) for i in items[8:]]  # noqa: E731
+    assert later(ia) == later(ib) and later(ia)[:8] != later(ia)[8:]
     again = _gen("backlog", BACKLOG, 1).setup_items()
     assert all(np.array_equal(x["prompt"], y["prompt"]) for x, y in zip(ia, again))
 

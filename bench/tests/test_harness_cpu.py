@@ -13,7 +13,7 @@ from harness import cell, judge, spec
 
 MODEL = dict(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=96,
              vocab_size=128, rope_theta=10000.0, param_dtype="bfloat16", compute_dtype="bfloat16")
-CONFIG = {"arch": "smollm-360m", "model": MODEL,
+CONFIG = {"arch": "smollm-360m", "reference": "llama", "model": MODEL,
           "weights": {"group": 32, "n_over_k": 1.0, "n_over_k_embed": 0.5},
           "engine": {"n_slots": 4, "max_len": 128, "n_pages": 64, "page": 8, "kv_group": 8,
                      "kv_pulses": 127, "prefill_chunk": 2, "prefix_cache": True}}
@@ -27,8 +27,8 @@ SEED = 2**31 + 77
 
 
 def _run(seconds=2.0, on_engine=None):
-    s = {"config": CONFIG, "traffic": TRAFFIC, "limits": LIMITS, "chips": 1,
-         "generator": spec.generator(TRAFFIC["generator"])}
+    s = {"config": CONFIG, "reference": spec.reference(CONFIG), "traffic": TRAFFIC, "limits": LIMITS,
+         "chips": 1, "generator": spec.generator(TRAFFIC["generator"])}
     return cell.run(s, SEED, seconds, False, t_start=time.perf_counter(),
                     require_tpu=False, on_engine=on_engine)
 
@@ -48,9 +48,11 @@ def test_control_is_not_correct():
     samples = [{"prompt": r.req.prompt, "served": r.req.generated} for r in recs]
     from harness import weights
 
-    _, raw = weights.make(CONFIG, SEED)
-    reads = judge.readings(weights.arch_of(CONFIG), raw, samples, CONFIG["engine"]["max_len"],
-                           control=True)
+    ref = spec.reference(CONFIG)
+    arch = ref.arch(weights.model_config(CONFIG))
+    _, raw = weights.make(arch, CONFIG, SEED, ref)
+    reads = judge.readings(ref, arch, raw, samples,
+                           CONFIG["engine"]["max_len"], control=True)
     assert all(c["ok"] for c in judge.checks(reads, LIMITS).values())
     assert not judge.checks(reads, LIMITS, key="control_gap")["widest_gap"]["ok"], reads
 

@@ -26,6 +26,7 @@ def test_every_piece_is_found_by_name():
         assert s["end_to_end"] and s["per_layer"]
         assert any(m["name"] == "setup_s" for m, _ in s["end_to_end"])
         assert all(hasattr(r, "read") for _, r in s["end_to_end"] + s["per_layer"])
+        assert all(hasattr(s["reference"], f) for f in ("arch", "view", "make_forward"))
     names = [m["name"] for m in b["end_to_end"] + b["per_layer"]] + [w["name"] for w in b["workloads"]]
     assert len(names) == len(set(names)) and all(NAME.match(n) for n in names)
     for m in b["per_layer"]:
@@ -43,7 +44,7 @@ def test_config_file_is_what_runs(conf):
     assert f["engine"]["max_len"] <= f["context_length"]
 
 
-SMALL = {"arch": "smollm-360m", "model": dict(
+SMALL = {"arch": "smollm-360m", "reference": "llama", "model": dict(
     n_layers=2, d_model=96, n_heads=4, n_kv_heads=2, head_dim=24, d_ff=160, vocab_size=64),
     "weights": {"group": 64, "n_over_k": 1.0, "n_over_k_embed": 0.5}, "engine": {"max_len": 64}}
 
@@ -56,7 +57,8 @@ def test_weights_fill_the_programs_own_tree():
     from repro.core.packed import is_packed
 
     tree = weights.program_tree(SMALL)
-    params, raw = weights.make(SMALL, 2**31 + 5)
+    ref = spec.reference(SMALL)
+    params, raw = weights.make(ref.arch(model_config(SMALL)), SMALL, 2**31 + 5, ref)
     assert jax.tree.structure(params) == jax.tree.structure(tree)  # packing metadata included
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(tree)):
         assert (a.shape, a.dtype) == (b.shape, b.dtype)
@@ -80,7 +82,8 @@ def test_weights_are_what_the_programs_encoder_gives():
     from repro.core.packed import is_packed, quantize_params
     from repro.core.quantize import QuantPolicy
 
-    params, _ = weights.make(SMALL, 11)
+    ref = spec.reference(SMALL)
+    params, _ = weights.make(ref.arch(model_config(SMALL)), SMALL, 11, ref)
     dense = jax.tree.map(lambda p: p.dequantize(jnp.float32) if is_packed(p) else p, params,
                          is_leaf=is_packed)
     policy = QuantPolicy(rules=(("embedding", 0.5, 64), ("kernel|experts", 1.0, 64)), scale_mode="ls")

@@ -56,8 +56,7 @@ def test_rooflines_read_the_kernels_by_their_compiled_names():
     """v3 and v4 are matched by the custom calls' names in the compiled
     decode program (``pvq_matmul_q.58``, ``pvq_attn_q.8``); other ops of
     the program, and the same kernels in another program, do not count."""
-    from harness import work
-
+    ref = spec.reference({"reference": "llama"})
     arch = {"n_layers": 2, "d_model": 960, "n_heads": 15, "n_kv_heads": 5, "head_dim": 64,
             "d_ff": 2560, "vocab_size": 4096}
     peaks = {"int8_ops_per_s": 393e12, "hbm_bytes_per_s": 819e9}
@@ -66,11 +65,11 @@ def test_rooflines_read_the_kernels_by_their_compiled_names():
                                      "pvq_attn_q.8": 1e6, "fusion.12": 9e6},
                   "jit__chunk_fn": {"pvq_matmul_q.3": 50e6, "pvq_attn_q.1": 50e6}}}
     run = _run(RECORDS, steps=[Step(11.0, 11.5, [40, 70])], trace=tr)
-    run.arch, run.peaks = arch, peaks
+    run.arch, run.peaks, run.ref = arch, peaks, ref
     run.config = {"engine": {"page": 32, "kv_group": 32}, "weights": {"group": 256}}
-    v3 = work.v3_step(arch, 2, 256, peaks)
-    v4 = work.v4_step(arch, [32, 64], 32, peaks)
+    v3 = ref.v3_step(arch, 2, 256, peaks)
+    v4 = ref.v4_step(arch, [32, 64], 32, peaks)
     assert _read("v3_roofline", run) == pytest.approx(100.0 * v3 / 4e-3)
     assert _read("v4_roofline", run) == pytest.approx(100.0 * v4 / 1e-3)
-    ops = work.decode_token_ops(arch, 40) + work.decode_token_ops(arch, 70)
+    ops = ref.decode_token_ops(arch, 40) + ref.decode_token_ops(arch, 70)
     assert _read("decode_mfu", run) == pytest.approx(100.0 * ops / (10.0 * 393e12))
